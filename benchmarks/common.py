@@ -9,7 +9,10 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_devices(code: str, n_devices: int = 8, timeout: int = 560) -> str:
+    """Run ``code`` in a child with ``n_devices`` host CPU devices (the
+    child stays off any accelerator the parent may already hold)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -61,7 +61,7 @@ def body(params, x, y):
                                 params, oc)
     return loss, params
 
-from repro.core.compat import shard_map
+from jax import shard_map
 fn = jax.jit(shard_map(body, mesh=mesh,
                            in_specs=(P(), P("data"), P("data")),
                            out_specs=(P(), P()), axis_names={{"data"}},

@@ -38,7 +38,7 @@ def main():
         y = cnn._conv1d(xt, w, b)
         return y  # [B, tile, Cout] after VALID conv over the halo'd tile
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
     fn = jax.jit(shard_map(
         lambda x, w, b: sharded_conv(x, w, b), mesh=mesh,
         in_specs=(P(None, "data"), P(), P()),

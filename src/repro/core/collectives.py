@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import compat
-
 
 # ---------------------------------------------------------------------------
 # Fused collectives over pytrees (tensor fusion applied to collectives)
@@ -44,7 +42,7 @@ def reduce_scatter(x: jax.Array, axis_name: str, *, axis: int = 0):
 
 
 def all_gather(x: jax.Array, axis_name: str, *, axis: int = 0):
-    return compat.all_gather(x, axis_name, axis=axis, tiled=True)
+    return lax.all_gather(x, axis_name, axis=axis, tiled=True)
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +60,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     collectives both lower to; having it explicit lets the pipeline examples
     overlap each hop with compute and lets tests count hops.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     if x.size % n != 0:  # fallback for indivisible payloads
@@ -81,7 +79,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
 
     # all-gather phase: row r of the gather holds chunk (r+1)%n, so chunk i
     # lives at row (i-1)%n.
-    full = compat.all_gather(send, axis_name, axis=0, tiled=False)
+    full = lax.all_gather(send, axis_name, axis=0, tiled=False)
     order = (jnp.arange(n) - 1) % n
     return full[order].reshape(x.shape)
 
@@ -93,7 +91,7 @@ def halo_exchange(x: jax.Array, axis_name: str, halo: int, *, dim: int = 0):
     returns the tile extended with received ghost cells (edge shards are
     zero-padded: non-periodic boundary).
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     lo = lax.slice_in_dim(x, 0, halo, axis=dim)
     hi = lax.slice_in_dim(x, x.shape[dim] - halo, x.shape[dim], axis=dim)
@@ -130,5 +128,5 @@ def softmax_combine(partials: tuple[jax.Array, jax.Array, jax.Array],
 # ---------------------------------------------------------------------------
 def pipeline_shift(x: jax.Array, axis_name: str, *, reverse: bool = False):
     """Hand activations (or grads, reverse) to the neighbouring stage."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return lax.ppermute(x, axis_name, _ring_perm(n, -1 if reverse else 1))

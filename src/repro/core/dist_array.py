@@ -15,7 +15,7 @@ from typing import Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import collectives, compat
+from . import collectives
 from .sharding import ShardingRules, sharding_for, spec_for
 
 
@@ -75,6 +75,6 @@ class TiledArray:
             return collectives.halo_exchange(x, axis, halo, dim=dim)
 
         out_parts = list(in_spec) + [None] * (len(self.dims) - len(in_spec))
-        fn = compat.shard_map(body, mesh=self.mesh, in_specs=in_spec,
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=in_spec,
                               out_specs=P(*out_parts), check_vma=False)
         return fn(self.data)

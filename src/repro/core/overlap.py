@@ -24,14 +24,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import compat, fusion
+from . import fusion
 from ..optim import optimizers as optim
 
 
 def dp_axis_size(dp_axes) -> jax.Array:
     n = 1
     for a in dp_axes:
-        n = n * compat.axis_size(a)
+        n = n * lax.axis_size(a)
     return n
 
 
@@ -93,11 +93,6 @@ def zero1_scatter_mask(param_specs, mesh, rules, ndp: int,
     from .sharding import ParamSpec, spec_for
 
     def decide(s: ParamSpec) -> bool:
-        if compat.NEEDS_DP_OPERAND_REPLICATION:
-            # old jax: the scatter path's collectives hit partial-manual
-            # partitioner bugs; fall back to dense (identical math, no
-            # solver-memory sharding)
-            return False
         if not s.shape or s.shape[0] % max(ndp, 1) or s.size < min_size:
             return False
         pspec = spec_for(mesh, rules, s.shape, s.dims)
@@ -164,16 +159,13 @@ def zero1_update(grads, opt_state, params, oc, dp_axes, scatter_mask):
     new_p, new_m, new_v = [], [], []
     for g_r, p, m, v, sc in zip(reduced, p_leaves, m_leaves, v_leaves, mask):
         if sc:
-            # never reached on old jax (zero1_scatter_mask gates the
-            # scatter path off there), so axis_index only traces where
-            # the partitioner supports it
             shard = m.shape[0]
             rank = lax.axis_index(axes)
             p_sh = lax.dynamic_slice_in_dim(
                 p.astype(jnp.float32), rank * shard, shard, axis=0)
             p2, m2, v2 = optim.zero1_shard_update(g_r, p_sh, m, v, count, oc,
                                                   clip)
-            p2 = compat.all_gather(p2, axes, axis=0, tiled=True)
+            p2 = lax.all_gather(p2, axes, axis=0, tiled=True)
         else:
             p2, m2, v2 = optim.zero1_shard_update(
                 g_r, p.astype(jnp.float32), m, v, count, oc, clip)

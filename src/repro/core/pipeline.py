@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import collectives, compat
+from . import collectives
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
@@ -40,7 +40,7 @@ def gpipe_apply(stage_fn: Callable, stage_params, x_micro, *,
     returns [M, mb, ...] outputs as produced by the last stage (replicated
     via the closing broadcast from the last stage).
     """
-    S = compat.axis_size(axis)
+    S = lax.axis_size(axis)
     sid = lax.axis_index(axis)
     M = x_micro.shape[0]
     T = M + S - 1
@@ -83,7 +83,7 @@ def make_pipeline_fn(stage_fn: Callable, mesh, *, axis: str = "stage",
         my = jax.tree.map(lambda p: p[0], stacked_params)  # local slice
         return gpipe_apply(stage_fn, my, x_micro, axis=axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_spec if param_spec is not None else P(axis), P()),
         out_specs=P(),

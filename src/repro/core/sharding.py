@@ -12,7 +12,9 @@ and activation with logical dim names and the engine turns them into concrete
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Mapping, Sequence
 
@@ -172,11 +174,26 @@ def initialize(spec: ParamSpec, key: jax.Array) -> jax.Array:
     raise ValueError(f"unknown init {spec.init}")
 
 
-def init_params(specs, key: jax.Array):
-    """Initialize a pytree of ParamSpec into a pytree of arrays."""
-    leaves, treedef = jax.tree.flatten(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init_leaf(spec: ParamSpec, sharding, key: jax.Array) -> jax.Array:
+    # one program per (spec, placement): the draw, scale and cast fuse, so
+    # a bf16 leaf never holds a float32 copy of itself on the device
+    x = initialize(spec, key)
+    if sharding is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, sharding)
+
+
+def init_params(specs, key: jax.Array, shardings=None):
+    """Initialize a pytree of ParamSpec into a pytree of arrays, each leaf
+    born where ``shardings`` (a matching tree of ``NamedSharding``; the
+    default device when None) places it."""
+    is_spec = lambda x: isinstance(x, ParamSpec)
+    leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
+    placed = ([None] * len(leaves) if shardings is None
+              else treedef.flatten_up_to(shardings))
     keys = jax.random.split(key, len(leaves))
-    arrs = [initialize(s, k) for s, k in zip(leaves, keys)]
+    arrs = [_init_leaf(s, sh, k) for s, sh, k in zip(leaves, placed, keys)]
     return jax.tree.unflatten(treedef, arrs)
 
 
@@ -196,30 +213,25 @@ def param_count(specs) -> int:
     return sum(s.size for s in leaves)
 
 
-def constrain(x: jax.Array, mesh: Mesh, rules: ShardingRules,
-              dims: Sequence[str | None]) -> jax.Array:
-    """with_sharding_constraint by logical dims (no-op outside jit/mesh)."""
-    try:
-        spec = spec_for(mesh, rules, x.shape, dims)
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
-        return x
-
-
 # ---------------------------------------------------------------------------
 # Activation constraint hook (sequence parallelism and friends)
 # ---------------------------------------------------------------------------
-# Installed by the step builder at trace time; model code calls
-# ``act_constrain(x, dims)`` between blocks.  When no hook is installed it is
-# a no-op, so models stay mesh-agnostic (R8).
+# Installed by a step function for the duration of its trace; model code
+# calls ``act_constrain(x, dims)`` between blocks.  Outside such a trace it
+# is a no-op, so models stay mesh-agnostic (R8) and never see the mesh of a
+# step traced earlier.
 _ACT_HOOK: list = [None]
 
 
-def set_act_hook(mesh: Mesh | None, rules: ShardingRules | None):
-    if mesh is None:
-        _ACT_HOOK[0] = None
-    else:
-        _ACT_HOOK[0] = (mesh, rules)
+@contextlib.contextmanager
+def act_hook(mesh: Mesh, rules: ShardingRules):
+    """Constrain activations by ``rules`` on ``mesh`` inside the block."""
+    prev = _ACT_HOOK[0]
+    _ACT_HOOK[0] = (mesh, rules)
+    try:
+        yield
+    finally:
+        _ACT_HOOK[0] = prev
 
 
 def act_constrain(x: jax.Array, dims: Sequence[str | None]) -> jax.Array:
@@ -227,8 +239,5 @@ def act_constrain(x: jax.Array, dims: Sequence[str | None]) -> jax.Array:
     if hook is None or len(dims) != x.ndim:
         return x
     mesh, rules = hook
-    try:
-        spec = spec_for(mesh, rules, x.shape, dims)
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
-        return x
+    spec = spec_for(mesh, rules, x.shape, dims)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

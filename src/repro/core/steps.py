@@ -22,11 +22,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat, fusion, overlap
+from . import fusion, overlap
 from .granularity import GrainPolicy
-from .sharding import (ShardingRules, default_rules, init_params,
-                       param_shardings, param_structs, set_act_hook,
-                       spec_for)
+from .sharding import (ShardingRules, act_hook, default_rules, init_params,
+                       param_shardings, param_structs, spec_for)
 from ..models.model import build_model
 from ..optim.optimizers import OptConfig
 from ..optim import optimizers as optim
@@ -139,19 +138,21 @@ class TrainStep:
         return dp_degree(self.mesh) if self.mesh is not None else 1
 
     def init(self, key):
-        params = init_params(self.specs, key)
-        params = jax.device_put(params, self.param_shardings)
-        if self.strategy.name == "zero1":
-            opt = overlap.zero1_init_state(self.specs, self.scatter_mask,
-                                           self._ndp())
-        else:
+        params = init_params(self.specs, key, self.param_shardings)
+
+        def opt_state(params):
+            if self.strategy.name == "zero1":
+                return overlap.zero1_init_state(self.specs, self.scatter_mask,
+                                                self._ndp())
             opt = optim.init(params, self.strategy.opt)
             if self.strategy.name == "onebit":
                 from ..optim.compression import ROW
                 ndp = self._ndp()
                 opt["ef"] = [jnp.zeros((ndp * b.size // ROW, ROW), jnp.float32)
                              for b in self.plan.buckets]
-        opt = jax.device_put(opt, self.opt_shardings)
+            return opt
+        # born sharded: no device holds a whole copy of the state
+        opt = jax.jit(opt_state, out_shardings=self.opt_shardings)(params)
         return params, opt
 
     def param_structs(self):
@@ -191,8 +192,7 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     rules = default_rules(sequence_parallel=strategy.sequence_parallel)
     p_shard = param_shardings(specs, mesh, rules)
     # manual axes of size 1 make every dp collective a no-op; drop them so
-    # the dp=1 case is a plain pjit program (old jax also cannot represent
-    # manual subgroups over size-1 axes)
+    # the dp=1 case is a plain pjit program
     axes = tuple(a for a in dp_axes(mesh) if mesh.shape[a] > 1)
     ndp = dp_degree(mesh)
     structs = param_structs(specs)
@@ -235,23 +235,16 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
                     jax.tree.map(lambda a, b: a + b / k, carry[1], g)), None
         zero_g = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32),
                               structs)
-        (l, g), _ = compat.layer_scan(acc,
-                                      (jnp.zeros((), jnp.float32), zero_g),
-                                      micro)
+        (l, g), _ = jax.lax.scan(acc, (jnp.zeros((), jnp.float32), zero_g),
+                                 micro)
         return l, g
 
     def body(params, opt_state, batch):
         # inside shard_map the batch dim is already local: constrain only
         # auto-axis (model) placements; seq joins under sequence parallelism
-        set_act_hook(mesh, rules.with_overrides(batch=None))
-        loss, grads = grads_of(params, batch)
+        with act_hook(mesh, rules.with_overrides(batch=None)):
+            loss, grads = grads_of(params, batch)
         loss = jax.lax.pmean(loss, axes) if axes else loss
-        if axes and compat.NEEDS_DP_OPERAND_REPLICATION:
-            # old-jax partial-manual workaround: the dp exchange below may
-            # psum tensors still sharded over the auto "model" axis
-            grads = compat.replicate_dp_operands(grads, mesh)
-            if strategy.name == "zero1":
-                params = compat.replicate_dp_operands(params, mesh)
         if strategy.name == "zero1":
             params, opt_state, m = overlap.zero1_update(
                 grads, opt_state, params, oc, axes, scatter_mask)
@@ -283,7 +276,7 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
         else:
             opt_specs = _opt_skeleton(oc)  # prefix tree of P()
         bspec = _batch_spec(mesh, "batch")
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), opt_specs, bspec),
             out_specs=(P(), P(), opt_specs),
@@ -362,8 +355,7 @@ class DDPStep:
     def init(self, key):
         """Deterministic (params, opt) - identical on every locality fed
         the same key."""
-        params = init_params(self.specs, key)
-        params = jax.device_put(params, self.param_shardings)
+        params = init_params(self.specs, key, self.param_shardings)
         opt = jax.device_put(optim.init(params, self.strategy.opt),
                              self.opt_shardings)
         return params, opt
@@ -409,8 +401,8 @@ def make_ddp_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     oc = strategy.opt
 
     def loss_and_bufs(params, batch):
-        set_act_hook(mesh, rules)
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        with act_hook(mesh, rules):
+            loss, grads = jax.value_and_grad(model.loss)(params, batch)
         return loss.astype(jnp.float32), fusion.pack(grads, gplan)
 
     b_shard = batch_shardings(cfg, mesh, shape)
@@ -421,7 +413,6 @@ def make_ddp_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
                       out_shardings=(repl, bufs_sh))
 
     def apply(bufs, params, opt_state):
-        set_act_hook(mesh, rules)
         grads = fusion.unpack(bufs, gplan)
         params, opt_state, m = optim.update(grads, opt_state, params, oc)
         return m["grad_norm"], params, opt_state
@@ -497,8 +488,8 @@ def make_prefill_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     S = shape["seq_len"]
 
     def fn(params, batch):
-        set_act_hook(mesh, rules)
-        return model.prefill(params, batch, S)
+        with act_hook(mesh, rules):
+            return model.prefill(params, batch, S)
 
     cache_sp = model.cache_specs(shape["global_batch"], S)
     cache_sh = param_shardings(cache_sp, mesh, rules)
@@ -526,8 +517,8 @@ def make_decode_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     cache_sh = param_shardings(cache_sp, mesh, rules)
 
     def fn(params, cache, batch, pos):
-        set_act_hook(mesh, rules)
-        return model.decode_step(params, cache, batch, pos)
+        with act_hook(mesh, rules):
+            return model.decode_step(params, cache, batch, pos)
 
     jitted = jax.jit(
         fn,

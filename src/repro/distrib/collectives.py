@@ -23,8 +23,8 @@ pluggable and the result bitwise reproducible across world sizes.
 
 Codecs (:data:`CODECS`): ``fp32`` ships raw little-endian float32 bucket
 bytes (``decode(encode(x))`` is bitwise ``x``); ``onebit`` quantizes
-each bucket to sign bits + per-row L1 scales via the
-``kernels/onebit.py`` Pallas kernels (interpreter mode on CPU), carrying
+each bucket to sign bits + per-row L1 scales via ``kernels.ops`` (the
+``kernels/onebit.py`` Pallas kernels on a TPU), carrying
 the persistent per-locality error-feedback residual of
 ``optim.compression.init_error_state`` across steps - wire cost drops to
 1 bit/element plus one float per 1024 elements (~1/31 of fp32).
@@ -38,6 +38,7 @@ a resume run - consistent with the SPMD save-abort story of §10.
 """
 from __future__ import annotations
 
+import math
 import threading
 from typing import Any, Callable, Optional
 
@@ -119,11 +120,11 @@ class OneBitCodec(GradCodec):
 
     Each bucket buffer is viewed as ``[R, 1024]`` (the plan pads buckets
     to a multiple of ``ROW * 32``); the running residual is folded in,
-    then the ``kernels/onebit.py`` Pallas kernels (interpreter mode off
-    TPU, via ``kernels.ops`` ``impl="interpret"``) produce the packed
-    sign bitmap, per-row L1 scales, and the new residual.  Wire format
-    per bucket: ``size/8`` bytes of little-endian uint32 sign words,
-    then ``R`` little-endian float32 scales.  The residual lives on this
+    then ``kernels.ops`` produces the packed sign bitmap, per-row L1
+    scales, and the new residual: the ``kernels/onebit.py`` Pallas
+    kernels on a TPU, their bit-identical jnp oracle elsewhere.  Wire
+    format per bucket: ``size/8`` bytes of little-endian uint32 sign
+    words, then ``R`` little-endian float32 scales.  The residual lives on this
     locality only - it is never exchanged or checkpointed, and resets
     with ``reset`` at run (or resume) start.
     """
@@ -144,8 +145,7 @@ class OneBitCodec(GradCodec):
         for i, buf in enumerate(bufs):
             g2d = jnp.reshape(jnp.asarray(buf, jnp.float32), (-1, ROW))
             packed, scale, self._err[i] = ops.onebit_quantize(
-                g2d, self._err[i], block_rows=g2d.shape[0],
-                impl="interpret")
+                g2d, self._err[i], block_rows=_block_rows(g2d.shape[0]))
             # the kernel returns scales lane-replicated [R, 128]; one
             # column is the wire form
             out.append(np.asarray(packed).tobytes()
@@ -162,12 +162,19 @@ class OneBitCodec(GradCodec):
         deq = ops.onebit_dequantize(
             jnp.asarray(packed),
             jnp.broadcast_to(jnp.asarray(scale), (rows, 128)),
-            block_rows=rows, impl="interpret")
+            block_rows=_block_rows(rows))
         return np.asarray(deq).reshape(-1)
 
     def wire_bytes(self, plan) -> int:
         from ..optim.compression import ROW
         return sum(b.size // 8 + 4 * (b.size // ROW) for b in plan.buckets)
+
+
+def _block_rows(rows: int) -> int:
+    """Kernel row block for a ``[rows, ROW]`` bucket: at most 256 rows
+    (about 1 MiB of f32 per operand block, well inside VMEM) and a
+    divisor of ``rows``; buckets are padded to multiples of 32 rows."""
+    return math.gcd(rows, 256)
 
 
 CODECS: dict[str, type] = {Fp32Codec.name: Fp32Codec,

@@ -417,6 +417,10 @@ def worker_main(rank: int, world: int, driver_addr, env: Optional[dict] = None):
     """
     for k, v in (env or {}).items():
         os.environ[k] = v
+    if "JAX_PLATFORMS" in os.environ:
+        # jax read the variable when this module imported it, before the
+        # export above: apply it before any backend starts
+        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     os.environ["PHYRAX_LOCALITY_RANK"] = str(rank)
     ckpt_dir = os.environ.get("PHYRAX_CKPT_DIR")
     if ckpt_dir:
@@ -498,7 +502,9 @@ class LocalityGroup:
     Args:
         n_workers: worker process count (world size is ``n_workers + 1``).
         worker_env: extra environment for the children (exported before
-            jax device setup in the child).
+            jax device setup in the child).  ``JAX_PLATFORMS=cpu`` is
+            always added: worker localities run host tasks only, and a
+            chip belongs to the one process that drives it.
         start_timeout: seconds to wait for all workers to report in.
         port: driver listen port (0 = ephemeral); pinned by elastic runs
             so ``--join`` has a known address to dial.
@@ -512,7 +518,7 @@ class LocalityGroup:
         # membership generation: bumped on every join and loss, gossiped
         # with the peer table, and carried by every steal (fencing)
         self.gen = 0
-        self._worker_env = worker_env
+        self._worker_env = dict(worker_env or {}, JAX_PLATFORMS="cpu")
         self._start_timeout = start_timeout
         self._addrs: dict[int, tuple[str, int]] = {}
         self._alive: set[int] = set()
@@ -529,7 +535,7 @@ class LocalityGroup:
             p = ctx.Process(
                 target=worker_main, daemon=True,
                 args=(rank, self.world, tuple(self.endpoint.address),
-                      worker_env))
+                      self._worker_env))
             p.start()
             self.procs[rank] = p
         with self._cond:

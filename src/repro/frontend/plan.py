@@ -227,6 +227,20 @@ class Session:
                 "Plan(elastic=True) does not compose with spmd or ddp: "
                 "their collectives assume a fixed world; elastic "
                 "membership is for the task-parallel runtime")
+        if plan.ddp or plan.spmd:
+            # SPMD mode must start jax.distributed before any backend, so
+            # it reads the pinned platform list instead of starting one
+            backend = ((jax.config.jax_platforms or "unpinned").split(",")[0]
+                       if plan.spmd else jax.default_backend())
+            if backend != "cpu":
+                raise ValueError(
+                    f"Plan(ddp=True) and Plan(spmd=True) compute on the CPU "
+                    f"backend in several processes (this driver's backend: "
+                    f"{backend}; SPMD needs JAX_PLATFORMS=cpu).  A chip "
+                    f"belongs to one process: train across chips in one "
+                    f"process with the in-mesh strategies instead - "
+                    f"Plan(data=..., model=..., strategy='phylanx' | "
+                    f"'horovod' | 'zero1' | 'onebit')")
         if plan.ddp:
             from ..distrib.collectives import CODECS
             if plan.grad_codec not in CODECS:
@@ -842,8 +856,8 @@ class Session:
         """
         plan, runtime, cfg = self.plan, self.runtime, self.cfg
         pre, dec = self._serve_steps_for(prompt_len, gen_len, slots)
-        params = init_params(pre.specs, jax.random.PRNGKey(plan.seed))
-        params = jax.device_put(params, pre.param_shardings)
+        params = init_params(pre.specs, jax.random.PRNGKey(plan.seed),
+                             pre.param_shardings)
 
         if prompts is None:
             rng = np.random.default_rng(plan.seed)
@@ -1025,8 +1039,8 @@ class Session:
                              "embedding); use serve()")
         pre1 = self._serve_steps_for(prompt_len, gen_len, 1)[0]
         dec = self._serve_steps_for(prompt_len, gen_len, slots)[1]
-        params = init_params(pre1.specs, jax.random.PRNGKey(plan.seed))
-        params = jax.device_put(params, pre1.param_shardings)
+        params = init_params(pre1.specs, jax.random.PRNGKey(plan.seed),
+                             pre1.param_shardings)
 
         if queue is None:
             q = RequestQueue()
@@ -1115,8 +1129,6 @@ class Session:
             step, lowered, compiled, t_lower, t_compile = lower_cell(
                 cfg, mesh, shape_name, self.strategy)
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # old jax: per-program dicts
-                ca = ca[0] if ca else {}
             try:
                 ma = compiled.memory_analysis()
                 mem = {

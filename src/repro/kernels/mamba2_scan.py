@@ -38,13 +38,20 @@ def _kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, s_scr, *,
     a = a_ref[0, 0].astype(jnp.float32)            # [1, c] row
     Bm = b_ref[0, 0].astype(jnp.float32)           # [c, N]
     Cm = c_ref[0, 0].astype(jnp.float32)           # [c, N]
-    a = a.reshape(chunk)
 
-    a_cs = jnp.cumsum(a)                           # [c]
-    # intra-chunk: L[t,s] = exp(a_cs[t]-a_cs[s]) for t>=s
-    diff = a_cs[:, None] - a_cs[None, :]
+    # running sums a_cs[t] = sum_{s<=t} a[s], as a column and as a row:
+    # two exact-f32 matmuls with the lower triangle (Mosaic has no cumsum)
     tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri_f = tri.astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+    a_cs = jax.lax.dot_general(tri_f, a, nt, precision=hi,
+                               preferred_element_type=jnp.float32)  # [c, 1]
+    a_cs_row = jax.lax.dot_general(a, tri_f, nt, precision=hi,
+                                   preferred_element_type=jnp.float32)
+    # intra-chunk: L[t,s] = exp(a_cs[t]-a_cs[s]) for t>=s
+    diff = a_cs - a_cs_row                         # [c, c]
     Lmat = jnp.where(tri, jnp.exp(diff), 0.0)
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [c, c]
@@ -52,14 +59,14 @@ def _kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, s_scr, *,
                             preferred_element_type=jnp.float32)   # [c, P]
 
     # inter-chunk: contribution of carried state
-    decay_from_start = jnp.exp(a_cs)[:, None]                      # [c, 1]
+    decay_from_start = jnp.exp(a_cs)                               # [c, 1]
     y = y + jax.lax.dot_general(Cm * decay_from_start, s_scr[...].T,
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
     # state update for the next chunk
-    a_tot = a_cs[-1]
-    decay_to_end = jnp.exp(a_tot - a_cs)[:, None]                  # [c, 1]
+    a_tot = jnp.sum(a, axis=1, keepdims=True)                      # [1, 1]
+    decay_to_end = jnp.exp(a_tot - a_cs)                           # [c, 1]
     s_new = jax.lax.dot_general(xdt, Bm * decay_to_end,
                                 (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [P,N]
@@ -89,7 +96,7 @@ def mamba2_chunk_scan(xdt, a, Bm, Cm, *, chunk: int = 128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, ic: (b, h, ic)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, ic: (b, h, 0, ic)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, ic: (b, h, ic, 0)),
         ],
@@ -103,5 +110,5 @@ def mamba2_chunk_scan(xdt, a, Bm, Cm, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xdt, a, Bm, Cm)
+    )(xdt, a[:, :, None, :], Bm, Cm)   # [B, H, 1, L]: a (1, chunk) tile
     return y, state

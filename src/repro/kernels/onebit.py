@@ -30,18 +30,39 @@ def _quant_kernel(g_ref, e_ref, packed_ref, scale_ref, err_ref):
     err_ref[...] = (q - deq).astype(err_ref.dtype)
     scale_ref[...] = jnp.broadcast_to(scale, scale_ref.shape).astype(
         scale_ref.dtype)
-    bm, C = q.shape
-    bits = signs.reshape(bm, C // 32, 32).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    packed_ref[...] = jnp.sum(bits * weights[None, None, :],
-                              axis=-1).astype(jnp.uint32)
+    packed_ref[...] = jax.lax.bitcast_convert_type(_pack_words(signs),
+                                                   jnp.uint32)
+
+
+def _pack_words(signs):
+    """bool [bm, C] -> int32 words [bm, C/32], bit j of word w = column
+    32w + j.  Mosaic cannot split the lane dim into [C/32, 32], so the
+    pack is two matmuls against place-value matrices, one per 16-bit
+    half: every product and partial sum is an integer below 2^16, exact
+    in bf16 inputs with f32 accumulation."""
+    C = signs.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C // 32), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C // 32), 1)
+    bit = row & 31
+    own = (row >> 5) == col
+    s = signs.astype(jnp.bfloat16)
+
+    def half(lo_bit):
+        here = own & (bit >= lo_bit) & (bit < lo_bit + 16)
+        w = jnp.where(here, 1 << jnp.maximum(bit - lo_bit, 0), 0)
+        return jnp.dot(s, w.astype(jnp.float32).astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32
+                       ).astype(jnp.int32)
+    return half(0) | (half(16) << 16)
 
 
 def _dequant_kernel(packed_ref, scale_ref, out_ref):
-    packed = packed_ref[...]                                  # [bm, C/32]
+    packed = jax.lax.bitcast_convert_type(packed_ref[...],
+                                          jnp.int32)           # [bm, C/32]
     scale = scale_ref[:, :1].astype(jnp.float32)              # [bm, 1]
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (packed[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
+    shifts = jnp.arange(32, dtype=jnp.int32)
+    bits = jax.lax.shift_right_logical(
+        packed[:, :, None], shifts[None, None, :]) & 1
     bm = packed.shape[0]
     signs = bits.reshape(bm, -1).astype(jnp.float32)
     out_ref[...] = ((2.0 * signs - 1.0) * scale).astype(out_ref.dtype)

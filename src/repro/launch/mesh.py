@@ -2,11 +2,6 @@
 is a FUNCTION, not a module-level constant: importing this module never
 touches jax device state.
 
-Version note: ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exist in newer jax releases.  All axes here are
-Auto-typed, which is also the default, so on older jax we simply build the
-mesh without the kwarg - same semantics either way.
-
 Multi-process note: when the multi-locality runtime (``repro.distrib``)
 spawns worker processes, each worker calls ``maybe_init_jax_distributed``
 before any device work.  It is a no-op unless ``PHYRAX_JAX_COORDINATOR``
@@ -17,28 +12,36 @@ must not stand up a coordination service it never uses.
 from __future__ import annotations
 
 import os
+import pathlib
 import socket
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax 0.4.x
-    _AxisType = None
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache for an entry point's
+    process and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, so a later run of the same
+    checkout finds what an earlier one compiled.  Entry points call this
+    in ``__main__``; library code and ``Session`` never do.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    checkout = pathlib.Path(__file__).resolve().parents[3]
+    path = str(checkout / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
               devices=None):
-    if _AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 axis_types=(_AxisType.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, devices=devices)
-    from jax.experimental import mesh_utils  # very old jax
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -35,6 +35,7 @@ import json
 
 
 from repro.frontend import cli_args, plan_from_args, serve_flags
+from repro.launch.mesh import use_compile_cache
 
 # result keys that serialize cleanly (handles hold threads and futures)
 _JSON_KEYS = ("requests", "completed", "cancelled", "expired", "failed",
@@ -89,4 +90,5 @@ def parser() -> argparse.ArgumentParser:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run(parser().parse_args())

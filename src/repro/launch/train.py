@@ -46,6 +46,7 @@ import json
 
 from repro.core.steps import Strategy
 from repro.frontend import cli_args, plan_from_args
+from repro.launch.mesh import use_compile_cache
 
 
 class _StallHook:
@@ -155,4 +156,5 @@ def parser() -> argparse.ArgumentParser:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run(parser().parse_args())

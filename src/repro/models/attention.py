@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..core import compat
 from ..core.sharding import ParamSpec
 from . import layers
 
@@ -202,7 +201,7 @@ def attend_chunked(q, k, v, *, causal: bool = True,
         m0 = jnp.full((B, H, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, q_chunk), jnp.float32)
         body = jax.checkpoint(block) if remat_chunks else block
-        (o, m, l), _ = compat.layer_scan(
+        (o, m, l), _ = jax.lax.scan(
             body, (o0, m0, l0),
             (jax.lax.slice_in_dim(kb, lo, hi + 1, axis=0),
              jax.lax.slice_in_dim(vb, lo, hi + 1, axis=0),

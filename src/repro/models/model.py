@@ -20,7 +20,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..core import compat
 from ..core.sharding import ParamSpec, act_constrain
 from . import blocks, layers, moe
 
@@ -97,7 +96,7 @@ class LM:
                 h = act_constrain(h, ("batch", "seq", "embed"))
                 return (h, aux + a), None
             body = _maybe_remat(body, cfg.remat)
-            (x, aux), _ = compat.layer_scan(
+            (x, aux), _ = jax.lax.scan(
                 body, (x, jnp.zeros((), jnp.float32)), params["stack"])
             return x, aux
 
@@ -107,11 +106,11 @@ class LM:
                                                 chunk=cfg.ssm_chunk), None
 
             def g_body(h, gp):
-                h, _ = compat.layer_scan(_maybe_remat(m_body, cfg.remat),
+                h, _ = jax.lax.scan(_maybe_remat(m_body, cfg.remat),
                                          h, gp["m"])
                 h = blocks.slstm_block_apply(h, gp["s"], cfg)
                 return h, None
-            x, _ = compat.layer_scan(g_body, x, params["stack"])
+            x, _ = jax.lax.scan(g_body, x, params["stack"])
             return x, jnp.zeros((), jnp.float32)
 
         if fam == "zamba":
@@ -123,13 +122,13 @@ class LM:
 
             def g_body(h, gp):
                 h = act_constrain(h, ("batch", "seq", "embed"))
-                h, _ = compat.layer_scan(_maybe_remat(m_body, cfg.remat),
+                h, _ = jax.lax.scan(_maybe_remat(m_body, cfg.remat),
                                          h, gp)
                 h, _ = blocks.tblock_apply(h, shared, cfg)
                 h = act_constrain(h, ("batch", "seq", "embed"))
                 return h, None
             g_fn = _maybe_remat(g_body, cfg.remat)
-            x, _ = compat.layer_scan(g_fn, x, params["stack"]["mamba"])
+            x, _ = jax.lax.scan(g_fn, x, params["stack"]["mamba"])
             return x, jnp.zeros((), jnp.float32)
 
         raise ValueError(fam)
@@ -298,7 +297,7 @@ class EncDec:
         def body(h, p):
             h, _ = blocks.tblock_apply(h, p, cfg, causal=False)
             return h, None
-        x, _ = compat.layer_scan(_maybe_remat(body, cfg.remat), x,
+        x, _ = jax.lax.scan(_maybe_remat(body, cfg.remat), x,
                                  params["enc_stack"])
         return layers.apply_norm(x, params["ln_enc"], cfg.norm)
 
@@ -323,7 +322,7 @@ class EncDec:
                             p["cross"]["wv"].astype(enc.dtype))
             h, _ = blocks.tblock_apply(h, p, cfg, enc_kv=(ck, cv))
             return h, None
-        x, _ = compat.layer_scan(_maybe_remat(body, cfg.remat), x,
+        x, _ = jax.lax.scan(_maybe_remat(body, cfg.remat), x,
                                  params["dec_stack"])
         x = layers.apply_norm(x, params["ln_f"], cfg.norm)
         return layers.logits(x, params["unembed"]), jnp.zeros((), jnp.float32)

@@ -16,7 +16,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..core import compat
 from ..core.sharding import ParamSpec
 from . import layers
 
@@ -144,7 +143,7 @@ def mamba2_chunked(x, p, cfg, *, chunk: int = 256, return_state: bool = False):
         return S_new, S_prev
 
     S0 = jnp.zeros((B, H, P, N), jnp.float32)
-    S_final, S_before = compat.layer_scan(
+    S_final, S_before = jax.lax.scan(
         scan_fn, S0,
         (jnp.moveaxis(S_chunk, 1, 0), jnp.moveaxis(a_tot, 1, 0)))
     S_before = jnp.moveaxis(S_before, 0, 1)                        # [B,nc,H,P,N]
@@ -324,7 +323,7 @@ def mlstm_chunked(x, p, cfg, *, chunk: int = 256, return_state: bool = False):
     n0 = jnp.zeros((B, H, P), jnp.float32)
     m0 = jnp.full((B, H), 0.0, jnp.float32)
     inputs = tuple(jnp.moveaxis(t, 1, 0) for t in (qc, kc, vc, ic, g, g_tot))
-    (Cf, nf, mf), hs = compat.layer_scan(body, (C0, n0, m0), inputs)
+    (Cf, nf, mf), hs = jax.lax.scan(body, (C0, n0, m0), inputs)
     h = jnp.moveaxis(hs, 0, 1).reshape(B, L, d_in)
 
     h = h + xi * p["skip"].astype(dt_f)
@@ -434,7 +433,7 @@ def slstm_apply(x, p, cfg, *, return_state: bool = False):
     st0 = (jnp.zeros((B, H, P), jnp.float32),
            jnp.zeros((B, H, P), jnp.float32),
            jnp.zeros((B, H), jnp.float32))
-    (hf, stf), hs = compat.layer_scan(step, (h0, st0),
+    (hf, stf), hs = jax.lax.scan(step, (h0, st0),
                                       jnp.moveaxis(pre, 1, 0))
     h = jnp.moveaxis(hs, 0, 1).reshape(B, L, D)
     h = layers.rms_norm(h, p["norm_w"])

@@ -7,14 +7,17 @@ credited by the paper to CNTK's 1-bit SGD, §3.7).
   2. all-gather the bitmaps+scales across the dp axes (wire ~ 1/30 of f32);
   3. dequantize every rank's contribution and average locally.
 
-The jnp pack/unpack here mirror kernels/onebit.py bit-for-bit (tested);
-on TPU the Pallas kernels take over via kernels/ops.py.
+The jnp pack/unpack here mirror kernels/onebit.py bit-for-bit (tested).
+The in-mesh ``onebit`` strategy runs these jnp functions on every
+backend; the Pallas kernels serve the fabric-DDP codec
+(``distrib/collectives.py``) through ``kernels/ops.py``.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
-from ..core import compat, fusion
+from ..core import fusion
 
 ROW = 1024  # bucket rows are reshaped to [R, ROW] for per-row scales
 
@@ -92,13 +95,13 @@ def exchange_onebit(grads, err_state, dp_axes, plan):
     axes = tuple(dp_axes)
     ndp = 1
     for a in axes:
-        ndp *= compat.axis_size(a)
+        ndp *= lax.axis_size(a)
     bufs = fusion.pack(grads, plan)
     out_bufs, new_err = [], []
     for buf, err in zip(bufs, err_state):
         packed, scale, err2 = quantize_bucket(buf, err)
-        all_packed = compat.all_gather(packed, axes, tiled=False)  # [ndp, R, C/32]
-        all_scale = compat.all_gather(scale, axes, tiled=False)    # [ndp, R, 1]
+        all_packed = lax.all_gather(packed, axes, tiled=False)  # [ndp, R, C/32]
+        all_scale = lax.all_gather(scale, axes, tiled=False)    # [ndp, R, 1]
         signs = unpack_bits(all_packed.reshape(-1, packed.shape[-1]))
         signs = signs.reshape((ndp,) + packed.shape[:1] + (-1,))
         deq = jnp.where(signs, all_scale, -all_scale)      # [ndp, R, ROW]

@@ -253,6 +253,24 @@ def test_plan_ddp_validation_errors():
         _plan(ddp_shards=3).compile()        # batch=4, shards=3
 
 
+def test_multiprocess_modes_refuse_a_driver_on_an_accelerator(monkeypatch):
+    """A chip belongs to one process: ddp and spmd name the in-mesh
+    strategies instead of spawning children that would need the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="in-mesh strategies"):
+        _plan().compile()
+    monkeypatch.undo()
+    # SPMD starts jax.distributed before any backend: it reads the
+    # pinned platform list instead
+    old = jax.config.jax_platforms
+    try:
+        jax.config.update("jax_platforms", "tpu")
+        with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+            _plan(ddp=False, spmd=True, localities=2).compile()
+    finally:
+        jax.config.update("jax_platforms", old)
+
+
 def test_make_ddp_step_rejects_unsupported_strategies():
     with pytest.raises(ValueError, match="zero1"):
         steps_lib.make_ddp_step(plan=_plan(strategy=Strategy(name="zero1")))

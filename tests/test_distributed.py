@@ -24,7 +24,7 @@ def test_fused_equals_naive_equals_ring_allreduce():
                 lambda x: collectives.ring_all_reduce(x, 'data'), t)
             return naive, fused, ring
 
-        from repro.core.compat import shard_map
+        from jax import shard_map
         f = shard_map(body, mesh=mesh, in_specs=P(),
                           out_specs=P(), check_vma=False)
         n, fu, r = f(tree)
@@ -53,7 +53,7 @@ def test_halo_exchange_matches_manual_shift():
         def body(t):
             return collectives.halo_exchange(t, 'data', 1, dim=0)
 
-        from repro.core.compat import shard_map
+        from jax import shard_map
         f = shard_map(body, mesh=mesh, in_specs=P('data'),
                           out_specs=P('data'), check_vma=False)
         out = np.asarray(f(xs))          # [4 shards x 6 rows, 1]
@@ -86,7 +86,7 @@ def test_flash_decode_combine_matches_full_softmax():
             o = jnp.exp(lg_l - m) @ v_l
             return collectives.softmax_combine((m, l, o), 'data')
 
-        from repro.core.compat import shard_map
+        from jax import shard_map
         f = shard_map(body, mesh=mesh, in_specs=(P('data'), P('data')),
                           out_specs=P(), check_vma=False)
         got = f(jax.device_put(lg, NamedSharding(mesh, P('data'))),

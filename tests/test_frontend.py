@@ -1,5 +1,6 @@
 """Frontend: @futurize tracing, Plan/Session, shared CLI flags, and parity
 with the launcher shims (resume-from-checkpoint drill)."""
+import os
 import time
 
 import numpy as np
@@ -201,3 +202,22 @@ def test_session_serve_zero_requests_serves_nothing():
                             verbose=False)
     assert out["requests"] == 0 and out["tokens"] == 0
     assert out["tokens_per_s"] == 0.0 and out["nodes"] == []
+
+
+def test_entry_points_place_the_compile_cache_from_outside(monkeypatch):
+    import jax
+
+    from repro.launch.mesh import use_compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/the/caller")
+    assert use_compile_cache() == "/set/by/the/caller"
+    assert jax.config.jax_compilation_cache_dir == old    # JAX reads it
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        checkout = os.path.dirname(path)
+        assert os.path.basename(path) == ".jax_cache"
+        assert os.path.isdir(os.path.join(checkout, "src", "repro"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -76,7 +76,7 @@ def test_collectives_inside_scan_are_multiplied():
             y, _ = jax.lax.scan(step, x, None, length=7)
             return y
 
-        from repro.core.compat import shard_map
+        from jax import shard_map
         f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(),
                                   out_specs=P(), check_vma=False))
         co = f.lower(jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()

@@ -250,13 +250,8 @@ def test_zero1_scatter_mask_rules():
     mask = overlap.zero1_scatter_mask(specs, mesh, default_rules(), ndp=1)
     assert mask == {"w": False, "b": False}
     mask16 = overlap.zero1_scatter_mask(specs, mesh, default_rules(), ndp=16)
-    from repro.core import compat
-    if compat.NEEDS_DP_OPERAND_REPLICATION:
-        # old jax: the scatter path is disabled wholesale (see overlap.py)
-        assert mask16 == {"w": False, "b": False}
-    else:
-        assert mask16["w"] is True      # 48 % 16 == 0, big, dim0 free
-        assert mask16["b"] is False     # too small / indivisible
+    assert mask16["w"] is True      # 48 % 16 == 0, big, dim0 free
+    assert mask16["b"] is False     # too small / indivisible
 
 
 # -- DDP gradient wire (DESIGN.md §11) ----------------------------------------
