@@ -745,7 +745,12 @@ class FuturizedGraph:
             try:
                 a, kw = jax.tree.map(resolve, (args, kwargs),
                                      is_leaf=_is_future)
-                value = fn(*a, **kw)
+                # a host span per node body, named by the node's kind
+                # (``decode:e3:t7`` -> ``node.decode``); a no-op untraced
+                with jax.profiler.TraceAnnotation(
+                        f"node.{node.name.split(':', 1)[0]}",
+                        name=node.name):
+                    value = fn(*a, **kw)
             except BaseException as e:  # noqa: BLE001 - propagated to deps
                 dt = time.perf_counter() - t1
                 with self._lock:

@@ -228,6 +228,11 @@ class InferenceCache:
         already has an entry - callers drop before re-putting.
         """
         import jax
+        with jax.profiler.TraceAnnotation("paging.put", rid=rid):
+            return self._put(rid, state)
+
+    def _put(self, rid: str, state: Any) -> int:
+        import jax
         leaves, treedef = jax.tree.flatten(state)
         arrs = [np.asarray(leaf) for leaf in leaves]
         blob = (np.concatenate([a.reshape(-1).view(np.uint8) for a in arrs])
@@ -255,6 +260,11 @@ class InferenceCache:
     def get(self, rid: str) -> Any:
         """The bit-identical state pytree parked by ``put``; None (a
         recorded miss) if ``rid`` has no entry."""
+        import jax
+        with jax.profiler.TraceAnnotation("paging.get", rid=rid):
+            return self._get(rid)
+
+    def _get(self, rid: str) -> Any:
         with self._lock:
             entry = self._entries.get(rid)
             if entry is None:

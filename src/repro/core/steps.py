@@ -239,7 +239,7 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
                                  micro)
         return l, g
 
-    def body(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
         # inside shard_map the batch dim is already local: constrain only
         # auto-axis (model) placements; seq joins under sequence parallelism
         with act_hook(mesh, rules.with_overrides(batch=None)):
@@ -277,12 +277,12 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
             opt_specs = _opt_skeleton(oc)  # prefix tree of P()
         bspec = _batch_spec(mesh, "batch")
         fn = jax.shard_map(
-            body, mesh=mesh,
+            train_step, mesh=mesh,
             in_specs=(P(), opt_specs, bspec),
             out_specs=(P(), P(), opt_specs),
             axis_names=set(axes), check_vma=False)
     else:
-        fn = body
+        fn = train_step
 
     # shardings for init/IO
     if strategy.name == "onebit":
@@ -487,13 +487,14 @@ def make_prefill_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     p_shard = param_shardings(specs, mesh, rules)
     S = shape["seq_len"]
 
-    def fn(params, batch):
+    def prefill_step(params, batch):
         with act_hook(mesh, rules):
             return model.prefill(params, batch, S)
 
     cache_sp = model.cache_specs(shape["global_batch"], S)
     cache_sh = param_shardings(cache_sp, mesh, rules)
-    jitted = jax.jit(fn, in_shardings=(p_shard, batch_shardings(scfg, mesh, shape)),
+    jitted = jax.jit(prefill_step,
+                     in_shardings=(p_shard, batch_shardings(scfg, mesh, shape)),
                      out_shardings=(NamedSharding(mesh, P()), cache_sh))
     return ServeStep(fn=jitted, model=model, specs=specs,
                      param_shardings=p_shard, cache_specs=cache_sp,
@@ -516,12 +517,12 @@ def make_decode_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
     cache_sp = model.cache_specs(B, S)
     cache_sh = param_shardings(cache_sp, mesh, rules)
 
-    def fn(params, cache, batch, pos):
+    def decode_step(params, cache, batch, pos):
         with act_hook(mesh, rules):
             return model.decode_step(params, cache, batch, pos)
 
     jitted = jax.jit(
-        fn,
+        decode_step,
         in_shardings=(p_shard, cache_sh, batch_shardings(scfg, mesh, shape),
                       NamedSharding(mesh, P())),
         out_shardings=(NamedSharding(mesh, P()), cache_sh),

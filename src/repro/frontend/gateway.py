@@ -64,6 +64,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.futures import FuturizedGraph, Lane
 from ..core.paging import InferenceCache, PagePool
@@ -78,6 +79,11 @@ class RequestRejected(RuntimeError):
 
 class DeadlineExpired(TimeoutError):
     """The request's deadline passed before it reached a decode slot."""
+
+
+def _us(seconds: float) -> int:
+    """Whole microseconds, as the ``*_us`` serve counters count them."""
+    return int(seconds * 1e6)
 
 
 def _stack_request(prompt):
@@ -445,7 +451,7 @@ class Gateway:
                                   self.pre.batch_shardings["tokens"])
             logits, cache1 = self.pre.fn(self.params, {"tokens": toks})
             first = int(np.asarray(jnp.argmax(logits, -1))[0])
-            state = jax.tree.map(np.asarray, cache1)
+            state = self._to_host(h.rid, cache1)
             self.runtime.record_serve(phase="prefill",
                                       dt_s=time.perf_counter() - t0)
             with self._lock:
@@ -454,10 +460,21 @@ class Gateway:
                 # park into the request's *current* replica: a migration
                 # mid-prefill parks into the old cache and the new
                 # replica's refill adopts the pages cross-replica
+                t1 = time.perf_counter()
                 self.replicas[h._replica].icache.put(h.rid, state)
-                h._last_t = time.perf_counter()
+                t2 = h._last_t = time.perf_counter()
+            self.runtime.record_serve(page_put_us=_us(t2 - t1))
             return first
         return prefill
+
+    def _to_host(self, rid: str, cache1):
+        """A batch-1 decode state pulled to the host, its bytes counted
+        as ``d2h_bytes``."""
+        with TraceAnnotation("gateway.cache_to_host", rid=rid):
+            state = jax.tree.map(np.asarray, cache1)
+        self.runtime.record_serve(
+            d2h_bytes=sum(a.nbytes for a in jax.tree.leaves(state)))
+        return state
 
     def _drop_pages(self, rid: str):
         """Free ``rid``'s pages wherever they are parked (a migrated
@@ -510,15 +527,21 @@ class Gateway:
         """Block for the request's prefill before giving it a slot; on
         failure (poison, upstream cancel) reclaim and report False.
         Idempotent on the token stream: a migrated request re-joining a
-        surviving replica's slot does not re-append its first token."""
+        surviving replica's slot does not re-append its first token.  The
+        time blocked counts as ``join_wait_us``."""
+        t0 = time.perf_counter()
         try:
-            h._first = h._prefill.result()
+            with TraceAnnotation("gateway.force_prefill", rid=h.rid):
+                h._first = h._prefill.result()
         except BaseException as e:  # noqa: BLE001 - resolved into the handle
             cancelled = isinstance(e, CancelledError)
             self._kill_admitted(h, e,
                                 "cancelled" if cancelled else "failed",
                                 "cancelled" if cancelled else "failed")
             return False
+        finally:
+            self.runtime.record_serve(
+                join_wait_us=_us(time.perf_counter() - t0))
         if not h._prefill_forced:
             h._prefill_forced = True
             with self._lock:
@@ -541,12 +564,17 @@ class Gateway:
                               self.pre.batch_shardings["tokens"])
         logits, cache1 = self.pre.fn(self.params, {"tokens": toks})
         first = int(np.asarray(jnp.argmax(logits, -1))[0])
-        return jax.tree.map(np.asarray, cache1), first
+        return self._to_host(rid, cache1), first
 
     def _refill_fn(self, rep: _Replica, joins: tuple):
+        """The refill node: each joiner's parked state paged in and its
+        row scattered into the batch (``page_get_us``, ``h2d_bytes``);
+        the whole body counts as ``refill_us``."""
         def refill(carry, *firsts):
+            t0 = time.perf_counter()
             tok, cache = carry if carry is not None else self._fresh_carry()
             for (slot, rid), first in zip(joins, firsts):
+                t1 = time.perf_counter()
                 with self._lock:
                     state = rep.icache.get(rid)
                     if state is None:
@@ -561,6 +589,8 @@ class Gateway:
                                     cross_replica_page_fetches=1,
                                     replica=rep.idx)
                                 break
+                self.runtime.record_serve(
+                    page_get_us=_us(time.perf_counter() - t1))
                 if state is None:
                     self.runtime.record_serve(prefill_recompute=1,
                                               replica=rep.idx)
@@ -568,17 +598,25 @@ class Gateway:
                 else:
                     self.runtime.record_serve(page_hits=1, replica=rep.idx)
 
+                moved = []
+
                 def scatter(c, s, sp, slot=slot):
                     ax = sp.dims.index("batch")
-                    row = jnp.asarray(np.take(s, 0, axis=ax))
+                    row = np.take(s, 0, axis=ax)
+                    moved.append(row.nbytes)
                     idx = (slice(None),) * ax + (slot,)
-                    return jnp.asarray(c).at[idx].set(row.astype(c.dtype))
-                cache = jax.tree.map(scatter, cache, state,
-                                     self.dec.cache_specs)
+                    return jnp.asarray(c).at[idx].set(
+                        jnp.asarray(row).astype(c.dtype))
+                with TraceAnnotation("gateway.scatter", rid=rid, slot=slot):
+                    cache = jax.tree.map(scatter, cache, state,
+                                         self.dec.cache_specs)
                 tok = tok.at[slot, 0].set(first)
-                self.runtime.record_serve(refills=1, replica=rep.idx)
+                self.runtime.record_serve(refills=1, h2d_bytes=sum(moved),
+                                          replica=rep.idx)
             tok = jax.device_put(tok, self.tok_sh)
             cache = jax.device_put(cache, self.dec.cache_shardings)
+            self.runtime.record_serve(
+                refill_us=_us(time.perf_counter() - t0), replica=rep.idx)
             return tok, cache
         return refill
 
@@ -725,132 +763,143 @@ class Gateway:
 
         try:
             while True:
-                now = time.perf_counter()
-                # 0. liveness: retire dead replicas, migrate their work
-                self._sweep_dead_replicas(round_)
-                # 1. ingest arrivals whose round has come
-                for h in queue.take_ready(round_):
-                    self._register(h)
-                    intake.append(h)
-                    pending.append(h)
-                # 2. queued-side faults: user cancels, expired deadlines
-                for h in list(pending):
-                    if h._cancel_requested:
-                        pending.remove(h)
-                        self._resolve(h, "cancelled",
-                                      CancelledError(h.rid), "cancelled")
-                    elif self._expired(h, now):
-                        pending.remove(h)
-                        self._resolve(h, "expired",
-                                      DeadlineExpired(h.rid), "expired")
-                # 3. admission: route + launch prefill chains up to the cap
-                while pending and inflight() < self.max_inflight:
-                    h = pending.popleft()
-                    rep = self._admit(h)
-                    rep.admitted.append(h)
-                # 4. admitted-side faults: cancel/expiry mid-prefill,
-                #    poisoned chains detected as soon as they are terminal
-                for rep in self.replicas:
-                    for h in list(rep.admitted):
-                        exc = None
+                with TraceAnnotation("gateway.round", round=round_):
+                    now = time.perf_counter()
+                    # 0. liveness: retire dead replicas, migrate their work
+                    self._sweep_dead_replicas(round_)
+                    # 1. ingest arrivals whose round has come
+                    for h in queue.take_ready(round_):
+                        self._register(h)
+                        intake.append(h)
+                        pending.append(h)
+                    # 2. queued-side faults: user cancels, expired deadlines
+                    for h in list(pending):
                         if h._cancel_requested:
-                            exc, status = CancelledError(h.rid), "cancelled"
+                            pending.remove(h)
+                            self._resolve(h, "cancelled",
+                                          CancelledError(h.rid), "cancelled")
                         elif self._expired(h, now):
-                            exc, status = DeadlineExpired(h.rid), "expired"
-                        elif (h._prefill.done()
-                              and h._prefill.exception() is not None):
-                            exc, status = h._prefill.exception(), "failed"
-                        if exc is not None:
-                            rep.admitted.remove(h)
-                            self.router.release(h.rid)
-                            self._kill_admitted(h, exc, status, status)
-                # 5/6 per replica: retire finished residents, fill free
-                #     slots from its admitted queue (prefill forced first:
-                #     a slot is only ever given a request whose state is
-                #     already parked in pages)
-                for rep in self.replicas:
-                    if not rep.alive:
-                        rep.round_work = (False, [])
-                        continue
-                    changed = False
-                    for s, h in enumerate(rep.residents):
-                        if h is None:
+                            pending.remove(h)
+                            self._resolve(h, "expired",
+                                          DeadlineExpired(h.rid), "expired")
+                    # 3. admission: route + launch prefill chains up to the cap
+                    while pending and inflight() < self.max_inflight:
+                        h = pending.popleft()
+                        rep = self._admit(h)
+                        rep.admitted.append(h)
+                    # 4. admitted-side faults: cancel/expiry mid-prefill,
+                    #    poisoned chains detected as soon as they are terminal
+                    for rep in self.replicas:
+                        for h in list(rep.admitted):
+                            exc = None
+                            if h._cancel_requested:
+                                exc = CancelledError(h.rid)
+                                status = "cancelled"
+                            elif self._expired(h, now):
+                                exc, status = DeadlineExpired(h.rid), "expired"
+                            elif (h._prefill.done()
+                                  and h._prefill.exception() is not None):
+                                exc, status = h._prefill.exception(), "failed"
+                            if exc is not None:
+                                rep.admitted.remove(h)
+                                self.router.release(h.rid)
+                                self._kill_admitted(h, exc, status, status)
+                    # 5/6 per replica: retire finished residents, fill free
+                    #     slots from its admitted queue (prefill forced first:
+                    #     a slot is only ever given a request whose state is
+                    #     already parked in pages)
+                    for rep in self.replicas:
+                        if not rep.alive:
+                            rep.round_work = (False, [])
                             continue
-                        cancelled = (h._cancel_requested
-                                     or (h.cancel_after is not None
-                                         and h._emitted >= h.cancel_after))
-                        if cancelled or h._emitted >= self.gen_len:
-                            fin = runtime.defer(
-                                self._finish_fn(h, cancelled), rep.prev_emit,
-                                lane=Lane.CHECKPOINT,
-                                name=f"finish:{h.rid}")
-                            finishes.append(fin)
-                            rep.residents[s] = None
-                            self.router.release(h.rid)
+                        changed = False
+                        for s, h in enumerate(rep.residents):
+                            if h is None:
+                                continue
+                            cancelled = (h._cancel_requested
+                                         or (h.cancel_after is not None
+                                             and h._emitted
+                                             >= h.cancel_after))
+                            if cancelled or h._emitted >= self.gen_len:
+                                fin = runtime.defer(
+                                    self._finish_fn(h, cancelled),
+                                    rep.prev_emit, lane=Lane.CHECKPOINT,
+                                    name=f"finish:{h.rid}")
+                                finishes.append(fin)
+                                rep.residents[s] = None
+                                self.router.release(h.rid)
+                                changed = True
+                        joiners = []
+                        free = [s for s in range(self.slots)
+                                if rep.residents[s] is None]
+                        while free and rep.admitted:
+                            h = rep.admitted.popleft()
+                            if not self._force_prefill(h):
+                                self.router.release(h.rid)
+                                continue
+                            s = free.pop(0)
+                            h._slot, h.status = s, "active"
+                            rep.residents[s] = h
+                            joiners.append((s, h))
                             changed = True
-                    joiners = []
-                    free = [s for s in range(self.slots)
-                            if rep.residents[s] is None]
-                    while free and rep.admitted:
-                        h = rep.admitted.popleft()
-                        if not self._force_prefill(h):
-                            self.router.release(h.rid)
+                        rep.round_work = (changed, joiners)
+                    # 7. nothing resident anywhere: fast-forward to the next
+                    #    arrival, block on the queue CV, or drain out
+                    if not any(rep.has_residents() for rep in self.replicas):
+                        nxt = queue.next_round()
+                        if nxt is not None:
+                            round_ = max(round_ + 1, nxt)
                             continue
-                        s = free.pop(0)
-                        h._slot, h.status = s, "active"
-                        rep.residents[s] = h
-                        joiners.append((s, h))
-                        changed = True
-                    rep.round_work = (changed, joiners)
-                # 7. nothing resident anywhere: fast-forward to the next
-                #    arrival, block on the queue CV, or drain out
-                if not any(rep.has_residents() for rep in self.replicas):
-                    nxt = queue.next_round()
-                    if nxt is not None:
-                        round_ = max(round_ + 1, nxt)
+                        if queue.drained():
+                            break
+                        # CV: submit()/close() wakes us
+                        with TraceAnnotation("gateway.idle_wait"):
+                            queue.wait_nonempty()
+                        round_ += 1
                         continue
-                    if queue.drained():
-                        break
-                    queue.wait_nonempty()   # CV: submit()/close() wakes us
-                    round_ += 1
-                    continue
-                # 8/9 per replica with residents: cut an epoch on
-                #     membership change (load pages), then one decode
-                #     round with per-slot positions and a chained emit
-                for rep in self.replicas:
-                    changed, joiners = rep.round_work
-                    if not rep.has_residents():
-                        continue
-                    if changed or rep.carry is None:
-                        rep.epoch += 1
-                        rep.j = 0
-                        joins = tuple((s, h.rid) for s, h in joiners)
+                    # 8/9 per replica with residents: cut an epoch on
+                    #     membership change (load pages), then one decode
+                    #     round with per-slot positions and a chained emit
+                    for rep in self.replicas:
+                        changed, joiners = rep.round_work
+                        if not rep.has_residents():
+                            continue
+                        if changed or rep.carry is None:
+                            rep.epoch += 1
+                            rep.j = 0
+                            joins = tuple((s, h.rid) for s, h in joiners)
+                            rep.carry = runtime.defer(
+                                self._refill_fn(rep, joins), rep.carry,
+                                *[h._prefill for _, h in joiners],
+                                name=f"refill:{rep.ns}e{rep.epoch}")
+                        live_rows = tuple((h._slot, h.rid)
+                                          for h in rep.residents
+                                          if h is not None)
+                        pos = np.full(self.slots, self.prompt_len, np.int32)
+                        for s, rid in live_rows:
+                            pos[s] = self.prompt_len \
+                                + self._handles[rid]._emitted
                         rep.carry = runtime.defer(
-                            self._refill_fn(rep, joins), rep.carry,
-                            *[h._prefill for _, h in joiners],
-                            name=f"refill:{rep.ns}e{rep.epoch}")
-                    live_rows = tuple((h._slot, h.rid)
-                                      for h in rep.residents if h is not None)
-                    pos = np.full(self.slots, self.prompt_len, np.int32)
-                    for s, rid in live_rows:
-                        pos[s] = self.prompt_len \
-                            + self._handles[rid]._emitted
-                    rep.carry = runtime.defer(
-                        self._decode_fn, rep.carry, jnp.asarray(pos),
-                        name=f"decode:{rep.ns}e{rep.epoch}:t{rep.j}")
-                    emit_deps = (rep.carry,) if rep.prev_emit is None \
-                        else (rep.carry, rep.prev_emit)
-                    rep.prev_emit = runtime.defer(
-                        self._emit_fn(rep, live_rows), *emit_deps,
-                        lane=Lane.CHECKPOINT,
-                        name=f"emit:{rep.ns}e{rep.epoch}:t{rep.j}")
-                    rep.emit_hist.append(rep.prev_emit)
-                    if len(rep.emit_hist) > self.lookahead:  # bound the
-                        rep.emit_hist.popleft().result()     # lead so
-                    for _, rid in live_rows:                 # faults land
-                        self._handles[rid]._emitted += 1
-                    rep.j += 1
-                round_ += 1
+                            self._decode_fn, rep.carry, jnp.asarray(pos),
+                            name=f"decode:{rep.ns}e{rep.epoch}:t{rep.j}")
+                        emit_deps = (rep.carry,) if rep.prev_emit is None \
+                            else (rep.carry, rep.prev_emit)
+                        rep.prev_emit = runtime.defer(
+                            self._emit_fn(rep, live_rows), *emit_deps,
+                            lane=Lane.CHECKPOINT,
+                            name=f"emit:{rep.ns}e{rep.epoch}:t{rep.j}")
+                        rep.emit_hist.append(rep.prev_emit)
+                        # bound the lead so faults land
+                        if len(rep.emit_hist) > self.lookahead:
+                            with TraceAnnotation(
+                                    "gateway.lookahead_wait",
+                                    replica=rep.idx, epoch=rep.epoch,
+                                    j=rep.j):
+                                rep.emit_hist.popleft().result()
+                        for _, rid in live_rows:
+                            self._handles[rid]._emitted += 1
+                        rep.j += 1
+                    round_ += 1
             # drain: force every replica's emit tail and every finish node
             for rep in self.replicas:
                 if rep.prev_emit is not None:
