@@ -47,10 +47,10 @@ surviving refill *adopts* the dead replica's pages (a counted
 recomputed.
 
 Token streams are *bit-identical* across co-tenancy AND across replica
-counts: prefill is batch=1, decode math is row-independent (one-hot cache
-writes, per-row masks and argmax), so a request's stream depends only on
-its prompt - the property the fault-injection, multiproc parity and
-replica-drill tests pin down.
+counts: prefill is batch=1, decode math is row-independent (each row
+writes only its own cache position; per-row masks and argmax), so a
+request's stream depends only on its prompt - the property the
+fault-injection, multiproc parity and replica-drill tests pin down.
 """
 from __future__ import annotations
 

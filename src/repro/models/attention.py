@@ -266,32 +266,43 @@ def decode_attend(q, k_cache, v_cache, pos, *, scale: Optional[float] = None,
     return o.reshape(B, 1, H, hd)
 
 
-def cache_update(k_cache, v_cache, k_new, v_new, pos, *, mode: str = "dus"):
-    """Write the new token's K/V at ``pos`` (scalar, or ``[B]`` for
-    per-row write offsets).
+def cache_update(k_cache, v_cache, k_new, v_new, pos, *, mode: str = "dus",
+                 layer=None):
+    """Write the new token's K/V (``[B,1,Hkv,hd]``) into each row's own
+    position ``pos`` (a scalar broadcasts to every row, or ``[B]``).
 
-    mode="dus": dynamic-update-slice (minimal write, but the SPMD
-    partitioner reshards a cache whose sequence dim is sharded).
+    The caches are ``[B,S,Hkv,hd]``, or a layer stack ``[L,B,S,Hkv,hd]``
+    written at index ``layer`` (the decode loop carries the stack and
+    writes it in place).  Each row writes only its own position, so the
+    write is row-independent, which the gateway's bit-identical streams
+    rely on; a row whose position lies outside ``[0, S)`` writes nothing.
+
+    mode="dus": one scatter of a single position per row, updated in place
+    (but the SPMD partitioner reshards a cache whose sequence dim is
+    sharded).
     mode="masked": one-hot select over the sequence dim - elementwise, so a
     sequence-sharded cache updates locally with zero collectives at the cost
-    of a full cache rewrite.  A ``[B]`` pos always takes this form: there
-    is no per-row dynamic-update-slice, and the one-hot write is exactly
-    row-independent, which the gateway's bit-parity guarantees rely on.
+    of rewriting the layer's whole cache.
     """
-    pos = jnp.asarray(pos)
-    if mode == "masked" or pos.ndim:
-        S = k_cache.shape[1]
-        hit = ((jnp.arange(S) == pos)[None, :, None, None] if pos.ndim == 0
-               else (jnp.arange(S)[None, :] == pos[:, None])[:, :, None,
-                                                             None])
-        k_cache = jnp.where(hit, k_new.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(hit, v_new.astype(v_cache.dtype), v_cache)
-        return k_cache, v_cache
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k_new.astype(k_cache.dtype), pos, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v_new.astype(v_cache.dtype), pos, axis=1)
-    return k_cache, v_cache
+    return (_write_rows(k_cache, k_new, pos, mode, layer),
+            _write_rows(v_cache, v_new, pos, mode, layer))
+
+
+def _write_rows(cache, new, pos, mode, layer):
+    B, S = cache.shape[-4:-2]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    new = new[:, 0].astype(cache.dtype)                    # [B, Hkv, hd]
+    if mode == "masked":
+        sl = (cache if layer is None
+              else jax.lax.dynamic_index_in_dim(cache, layer, 0, False))
+        hit = (jnp.arange(S)[None, :] == pos[:, None])[:, :, None, None]
+        sl = jnp.where(hit, new[:, None], sl)
+        return (sl if layer is None
+                else jax.lax.dynamic_update_index_in_dim(cache, sl, layer, 0))
+    rows = jnp.arange(B)
+    idx = (rows, pos) if layer is None else (layer, rows, pos)
+    return cache.at[idx].set(new, mode="drop", unique_indices=True,
+                             wrap_negative_indices=False)
 
 
 # ---------------------------------------------------------------------------

@@ -71,40 +71,52 @@ def kv_cache_specs(cfg, batch: int, seq: int, n_layers: Optional[int] = None,
 def tblock_decode(x, p, cfg, cache, pos, *, enc_kv=None):
     """x: [B,1,D]; cache: {"k","v"} [B,S,Hkv,hd]; pos: scalar int, or
     ``[B]`` per-row positions (continuous batch, one offset per slot)."""
+    q, k, v = tblock_decode_project(x, p, cfg, pos)
+    kc, vc = attention.cache_update(cache["k"], cache["v"], k, v, pos,
+                                    mode=cfg.cache_update)
+    new_cache = {"k": kc, "v": vc}
+    if "cross" in p:
+        if enc_kv is None:
+            enc_kv = (cache["ck"], cache["cv"])
+        new_cache["ck"], new_cache["cv"] = enc_kv
+    return tblock_decode_attend(x, p, cfg, q, kc, vc, pos,
+                                enc_kv=enc_kv), new_cache
+
+
+def tblock_decode_project(x, p, cfg, pos):
+    """The block up to the cache write: the new token's q, k, v
+    (``[B,1,H|Hkv,hd]``), RoPE'd at each row's position."""
     h = layers.apply_norm(x, p["ln_attn"], cfg.norm)
     pos = jnp.asarray(pos)
     positions = (pos[:, None] if pos.ndim
                  else jnp.full((h.shape[0], 1), pos))
-    q, k, v = attention.project_qkv(
+    return attention.project_qkv(
         h, p["attn"], positions=positions,
         rope_theta=cfg.rope_theta, use_rope=cfg.use_rope)
-    kc, vc = attention.cache_update(cache["k"], cache["v"], k, v, pos,
-                                    mode=cfg.cache_update)
+
+
+def tblock_decode_attend(x, p, cfg, q, kc, vc, pos, *, enc_kv=None):
+    """The block after the cache write: attention over the written cache
+    ``kc``/``vc`` [B,S,Hkv,hd], then cross-attention over ``enc_kv`` and
+    the MLP or MoE -> x."""
     o = attention.decode_attend(q, kc, vc, pos, window=cfg.sliding_window)
     x = x + jnp.einsum("bqhk,hkd->bqd", o, p["attn"]["wo"].astype(x.dtype))
-    new_cache = {"k": kc, "v": vc}
     if "cross" in p:
         h = layers.apply_norm(x, p["ln_cross"], cfg.norm)
         q = jnp.einsum("bsd,dhk->bshk", h, p["cross"]["wq"].astype(x.dtype))
         if "bq" in p["cross"]:
             q = q + p["cross"]["bq"].astype(x.dtype)
-        ck, cv = enc_kv if enc_kv is not None else (cache["ck"], cache["cv"])
+        ck, cv = enc_kv
         o = attention.attend_full(q, ck, cv, causal=False)
         x = x + jnp.einsum("bqhk,hkd->bqd", o,
                            p["cross"]["wo"].astype(x.dtype))
-        if enc_kv is None:
-            new_cache.update({"ck": ck, "cv": cv})
-        else:
-            new_cache.update({"ck": ck, "cv": cv})
     h = layers.apply_norm(x, p["ln_mlp"], cfg.norm)
     if "moe" in p:
         y, _ = moe.apply_moe(h, p["moe"], top_k=cfg.top_k,
                              group_size=cfg.moe_group,
                              dispatch=cfg.moe_dispatch)
-        x = x + y
-    else:
-        x = x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind)
-    return x, new_cache
+        return x + y
+    return x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind)
 
 
 # ---------------------------------------------------------------------------

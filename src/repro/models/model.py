@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.sharding import ParamSpec, act_constrain
-from . import blocks, layers, moe
+from . import attention, blocks, layers
 
 
 def stack_specs(tree, n: int):
@@ -221,11 +221,22 @@ class LM:
         x = layers.embed(batch["tokens"], params["embed"]).astype(cfg.c_dtype)
 
         if fam in ("dense", "moe"):
-            def body(h, pc):
-                p, c = pc
-                h, c2 = blocks.tblock_decode(h, p, cfg, c, pos)
-                return h, c2
-            x, cache = jax.lax.scan(body, x, (params["stack"], cache))
+            # the stacked cache rides in the carry and each layer writes one
+            # position per row into it in place; as xs/ys it would be copied
+            # slice by slice into a new stack, and that stack out again
+            def body(carry, p):
+                h, i, kc, vc = carry
+                q, k, v = blocks.tblock_decode_project(h, p, cfg, pos)
+                kc, vc = attention.cache_update(
+                    kc, vc, k, v, pos, mode=cfg.cache_update, layer=i)
+                h = blocks.tblock_decode_attend(
+                    h, p, cfg, q, jax.lax.dynamic_index_in_dim(kc, i, 0, False),
+                    jax.lax.dynamic_index_in_dim(vc, i, 0, False), pos)
+                return (h, i + 1, kc, vc), None
+            (x, _, k, v), _ = jax.lax.scan(
+                body, (x, jnp.int32(0), cache["k"], cache["v"]),
+                params["stack"])
+            cache = {"k": k, "v": v}
         elif fam == "xlstm":
             def m_body(h, pc):
                 p, c = pc

@@ -6,6 +6,9 @@ refuse what the chip's compiler would refuse (block tiling, VMEM, memory).
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,3 +109,49 @@ def test_qwen3_4b_decode_step_compiles_and_fits_one_chip(topo):
     mem = compiled.memory_analysis()
     assert cfg.n_layers == 36
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _chat_decode_step(topo, mode):
+    """The qwen3-4b decode step at the chat cell's 20 slots x 640, compiled
+    with cache write ``mode``; returns (compiled, the K cache's struct)."""
+    cfg = dataclasses.replace(get_config("qwen3-4b"), cache_update=mode)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    slots, cache_len = 20, 640
+    shape = {"seq_len": cache_len, "global_batch": slots, "kind": "decode"}
+    step = steps_lib.make_decode_step(cfg, mesh, steps_lib.Strategy(), shape)
+    cache = param_structs(step.cache_specs)
+    assert cache["k"].shape == (36, slots, cache_len, 8, 128)
+    compiled = step.fn.lower(
+        param_structs(step.specs), cache,
+        steps_lib.input_specs(steps_lib._serve_cfg(cfg), shape),
+        jax.ShapeDtypeStruct((slots,), jnp.int32)).compile()
+    return compiled, cache["k"]
+
+
+def _ops(text, op, shape):
+    """HLO lines whose result of ``shape`` comes from ``op``."""
+    out = "bf16[%s]" % ",".join(map(str, shape))
+    pat = r"= %s(\{[^}]*\})? %s\(" % (re.escape(out), op)
+    return [ln for ln in text.splitlines() if re.search(pat, ln)]
+
+
+def test_qwen3_4b_decode_step_writes_the_cache_in_place(topo):
+    """At the chat cell's 20 slots x 640, the decode step writes the stacked
+    KV cache where it lies: no temporary the size of the cache, and no copy
+    of the whole stack (an xs/ys layer scan makes both)."""
+    compiled, k = _chat_decode_step(topo, "dus")
+    cache_bytes = 2 * k.size * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 8
+    text = compiled.as_text()
+    assert not _ops(text, "copy", k.shape)
+    assert _ops(text, "scatter", k.shape)
+
+
+def test_qwen3_4b_masked_decode_step_keeps_the_one_hot_write(topo):
+    """mode="masked" (for a cache whose sequence dim is sharded) selects
+    each layer's whole slice against a one-hot mask, and scatters nothing."""
+    compiled, k = _chat_decode_step(topo, "masked")
+    text = compiled.as_text()
+    layer = k.shape[1:]
+    assert _ops(text, "select", layer) or _ops(text, "select", (1,) + layer)
+    assert not _ops(text, "scatter", k.shape)
