@@ -114,18 +114,26 @@ def compile_free_window(clock: CompileClock, trace: bool, counters=None):
         win.compiles = clock.compiles - c0
         if trace:
             span.__exit__(None, None, None)
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            t1 = time.perf_counter()
             try:
                 win.trace = reduce_trace(tdir)
             finally:
                 shutil.rmtree(tdir, ignore_errors=True)
+            log(f"profiler: trace stopped in {t1 - t0:.1f} s, reduced in "
+                f"{time.perf_counter() - t1:.1f} s")
+
+
+def memory_peaks(chips: int) -> list:
+    """Peak bytes in use on each of the cell's chips."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.devices()[:chips]]
 
 
 def memory_peak(chips: int) -> int:
     """Peak bytes in use on the fullest of the cell's chips."""
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.devices()[:chips]]
-    return int(max(peaks))
+    return max(memory_peaks(chips))
 
 
 def live_bytes() -> int:

@@ -76,3 +76,15 @@ def prefill(m: dict, prompt: int) -> tuple:
               + prompt * m["d"] * BF16)
     return flops, bytes_
 
+
+
+def train(m: dict, seqs: int, seq_len: int) -> int:
+    """Flops of training steps over ``seqs`` sequences of ``seq_len``
+    tokens: 6 per matmul weight and token (forward, and the backward's two
+    products) over every layer's matrices and the unembedding (the
+    embedding is a lookup), plus causal attention three times over (each
+    query sees the keys up to its own).  Recomputation is not counted."""
+    tokens = seqs * seq_len
+    weights = m["L"] * matmul_params(m) + m["d"] * m["V"]
+    keys = seqs * seq_len * (seq_len + 1) // 2
+    return 6 * weights * tokens + 3 * attn_flops(m, keys)

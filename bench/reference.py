@@ -25,9 +25,20 @@ in.  The program under test draws its own weights from the same
 seed by the same rule; ``bench/tests`` checks that the two agree bit for
 bit.
 
-``quant="fp8"`` is the control of the serving comparison: the same forward
-with every weight matrix (per tensor) and every activation entering a
-matmul (per row) rounded to float8 e4m3 with an amax scale.
+``quant="fp8"`` is the control of both comparisons: the same forward with
+every weight matrix (per tensor) and every activation entering a matmul
+(per row) rounded to float8 e4m3 with an amax scale.  In a training step
+the gradient of each rounded value is rounded to float8 e5m2 in the same
+way, as an fp8 training step computes.
+
+Training: ``loss_and_grad`` is next-token cross-entropy and its gradient
+by ``jax.value_and_grad``, every layer under ``jax.checkpoint`` and the
+gradient summed in float32 over micro-batches of rows; ``adamw`` is the
+plain AdamW step with the gradient clipped by its global norm.
+
+This module is the default reference of a configuration file; one that
+names another (``"reference": "<module>"``) gets ``bench/<module>.py``,
+which provides ``dims``, ``make_weights`` and ``plan_overrides`` too.
 """
 from __future__ import annotations
 
@@ -52,8 +63,20 @@ def dims(cfg: dict) -> dict:
             "bias": bool(cfg.get("attention_bias", False))}
 
 
+def plan_overrides(cfg: dict) -> dict:
+    """The program's ``Plan`` overrides that set every size of the
+    configuration file."""
+    m = dims(cfg)
+    return {"n_layers": m["L"], "d_model": m["d"], "n_heads": m["H"],
+            "n_kv_heads": m["Hkv"], "head_dim": m["hd"], "d_ff": m["ff"],
+            "vocab": m["V"], "rope_theta": m["theta"],
+            "qk_norm": m["qk_norm"], "qkv_bias": m["bias"]}
+
+
 def weight_rule(cfg: dict) -> list:
-    """``(path, shape, init, scale)`` of every leaf, in draw order."""
+    """``(path, shape, init, scale)`` of every leaf, in draw order: the
+    program's own initialisation, or with ``"weights":
+    "initializer_range"`` in the configuration file, Hugging Face's."""
     m = dims(cfg)
     L, d, H, Hkv, hd, ff, V = (m[k] for k in
                                ("L", "d", "H", "Hkv", "hd", "ff", "V"))
@@ -74,6 +97,12 @@ def weight_rule(cfg: dict) -> list:
               ("stack", "mlp", "w_down"): ((L, ff, d), "normal"),
               ("unembed", "w"): ((d, V), "normal")}
     leaves.update({("stack", "attn", k): v for k, v in attn.items()})
+    if cfg.get("weights") == "initializer_range":
+        # Hugging Face's own initialisation: every matrix and the embedding
+        # N(0, initializer_range), norms ones, biases zeros
+        std = float(cfg["initializer_range"])
+        return [(p, s, "scaled" if init in ("normal", "scaled") else init,
+                 std) for p, (s, init) in sorted(leaves.items())]
     return [(p, s, init, 0.02 if init == "scaled" else 1.0)
             for p, (s, init) in sorted(leaves.items())]
 
@@ -89,14 +118,41 @@ def _draw(shape, init, scale, key, dtype):
     return (std * jax.random.normal(key, shape)).astype(dtype)
 
 
-def make_weights(cfg: dict, seed: int, dtype=jnp.bfloat16) -> dict:
-    """All weights from the seed, one jitted program per leaf (so no leaf
-    holds a float32 copy beside the others); a flat dict keyed by path."""
+def iter_weights(cfg: dict, seed: int, dtype=jnp.bfloat16, sharding=None):
+    """``(path, array)`` of every leaf in draw order, each drawn by its own
+    jitted program when it is reached (so no leaf holds a float32 copy
+    beside the others).  ``sharding(path, shape)`` places a leaf as it is
+    born."""
     rule = weight_rule(cfg)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(rule))
-    return {path: jax.jit(functools.partial(_draw, shape, init, scale,
-                                            dtype=dtype))(k)
-            for (path, shape, init, scale), k in zip(rule, keys)}
+    for (path, shape, init, scale), k in zip(rule, keys):
+        out = None if sharding is None else sharding(path, shape)
+        yield path, jax.jit(functools.partial(_draw, shape, init, scale,
+                                              dtype=dtype),
+                            out_shardings=out)(k)
+
+
+def make_weights(cfg: dict, seed: int, dtype=jnp.bfloat16,
+                 sharding=None) -> dict:
+    """All weights from the seed; a flat dict keyed by path."""
+    return dict(iter_weights(cfg, seed, dtype, sharding))
+
+
+def spread(devices) -> callable:
+    """A ``sharding(path, shape)`` for ``make_weights`` that splits each
+    leaf over ``devices`` along its largest axis that they divide
+    (replicated where none does)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(devices), ("x",))
+    n = len(devices)
+
+    def sharding(path, shape):
+        axes = [i for i in np.argsort(shape)[::-1] if shape[i] % n == 0]
+        spec = [None] * len(shape)
+        if axes:
+            spec[axes[0]] = "x"
+        return NamedSharding(mesh, P(*spec))
+    return sharding
 
 
 # -- the forward --------------------------------------------------------------
@@ -113,15 +169,36 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _fp8(x, axes):
-    """Round to float8 e4m3 with an amax scale over ``axes``."""
-    s = jnp.max(jnp.abs(x), axis=axes, keepdims=True) / 448.0
+def _round(x, axes, dtype):
+    """Round to ``dtype`` (a float8) with an amax scale over ``axes``."""
+    s = jnp.max(jnp.abs(x), axis=axes, keepdims=True) / float(
+        jnp.finfo(dtype).max)
     s = jnp.where(s > 0, s, 1.0)
-    return (x / s).astype(jnp.float8_e4m3fn).astype(x.dtype) * s
+    return (x / s).astype(dtype).astype(x.dtype) * s
 
 
-def _hidden(m: dict, w: dict, tokens, quant=None):
-    """Final normed hidden states [S, d] of one sequence, in float32."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _fp8(x, axes):
+    """Round to float8 e4m3 with an amax scale over ``axes``; in the
+    backward pass the incoming gradient is rounded to float8 e5m2 with its
+    own amax scale over the same axes (fp8 training's two formats)."""
+    return _round(x, axes, jnp.float8_e4m3fn)
+
+
+def _fp8_fwd(x, axes):
+    return _fp8(x, axes), None
+
+
+def _fp8_bwd(axes, _, g):
+    return (_round(g, axes, jnp.float8_e5m2),)
+
+
+_fp8.defvjp(_fp8_fwd, _fp8_bwd)
+
+
+def _hidden(m: dict, w: dict, tokens, quant=None, remat=False):
+    """Final normed hidden states [S, d] of one sequence, in float32;
+    ``remat`` recomputes each layer in the backward pass."""
     qw = (lambda a: _fp8(a, None)) if quant == "fp8" else (lambda a: a)
     qa = ((lambda a, axes=(-1,): _fp8(a, axes)) if quant == "fp8"
           else (lambda a, axes=(-1,): a))
@@ -156,7 +233,7 @@ def _hidden(m: dict, w: dict, tokens, quant=None):
             * (h @ qw(p[("mlp", "w_up")]))
         return x + qa(a) @ qw(p[("mlp", "w_down")]), None
 
-    x, _ = jax.lax.scan(layer, x, stack)
+    x, _ = jax.lax.scan(jax.checkpoint(layer) if remat else layer, x, stack)
     return _rms(x, w[("ln_f", "w")].astype(jnp.float32), m["eps"])
 
 
@@ -196,3 +273,85 @@ def served_gaps(cfg: dict, w: dict, prompt, served, *, control: bool = False):
     top = jnp.argmax(logits(cfg, w, seq, first=first, quant="fp8"), -1)
     ctl = np.asarray(best - jnp.take_along_axis(ref, top[:, None], -1)[:, 0])
     return gap, ctl
+
+
+# -- training -----------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("m", "quant", "rows", "place"))
+def _loss_and_grad(m, w, tokens, labels, *, quant=None, rows=1, place=()):
+    m = dict(m)
+    qw = (lambda a: _fp8(a, None)) if quant == "fp8" else (lambda a: a)
+    qa = (lambda a: _fp8(a, (-1,))) if quant == "fp8" else (lambda a: a)
+    # keep every gradient where its weight lives
+    pin = (lambda t: {k: jax.lax.with_sharding_constraint(v, dict(place)[k])
+                      for k, v in t.items()}) if place else (lambda t: t)
+
+    def loss(w, tok, lab):                       # [rows, S] -> mean NLL
+        h = jax.vmap(lambda t: _hidden(m, w, t, quant, remat=True))(tok)
+        lg = qa(h) @ qw(w[("unembed", "w")])
+        lse = jax.scipy.special.logsumexp(lg, -1)
+        ll = jnp.take_along_axis(lg, lab[..., None], -1)[..., 0]
+        return jnp.mean(lse - ll)
+
+    B = tokens.shape[0]
+    micro = lambda a: a.reshape((B // rows, rows) + a.shape[1:])
+
+    def acc(carry, mb):
+        l, g = jax.value_and_grad(loss)(w, *mb)
+        return (carry[0] + l, pin(jax.tree.map(jnp.add, carry[1], g))), None
+
+    zero = (jnp.zeros((), jnp.float32), pin(jax.tree.map(jnp.zeros_like, w)))
+    with jax.default_matmul_precision("highest"):
+        (l, g), _ = jax.lax.scan(acc, zero, (micro(tokens), micro(labels)))
+    k = B // rows
+    return l / k, pin(jax.tree.map(lambda a: a / k, g))
+
+
+def loss_and_grad(cfg: dict, w: dict, batch: dict, quant=None,
+                  rows: int = 1):
+    """Mean next-token cross-entropy of ``batch`` (``tokens`` and
+    ``labels``, [B, S]) and its gradient for every leaf of ``w`` (float32),
+    summed over micro-batches of ``rows`` rows.  Each gradient is placed
+    as its weight is."""
+    m = tuple(sorted(dims(cfg).items()))
+    place = tuple(sorted((k, v.sharding) for k, v in w.items()
+                         if isinstance(v, jax.Array)
+                         and len(v.sharding.device_set) > 1))
+    return _loss_and_grad(m, w, jnp.asarray(batch["tokens"], jnp.int32),
+                          jnp.asarray(batch["labels"], jnp.int32),
+                          quant=quant, rows=rows,
+                          place=place if len(place) == len(w) else ())
+
+
+def global_norm(tree: dict):
+    return jnp.sqrt(sum(jnp.sum(jnp.square(a)) for a in tree.values()))
+
+
+@functools.partial(jax.jit, static_argnames=("opt",), donate_argnums=(0, 2))
+def _adamw(w, g, state, count, *, opt):
+    o = dict(opt)
+    gn = global_norm(g)
+    scale = jnp.minimum(1.0, o["grad_clip"] / jnp.maximum(gn, 1e-9))
+    new_w, new_s = {}, {}
+    for k in w:
+        gk = g[k] * scale
+        mk = o["b1"] * state[k][0] + (1 - o["b1"]) * gk
+        vk = o["b2"] * state[k][1] + (1 - o["b2"]) * gk * gk
+        mh = mk / (1 - o["b1"] ** count)
+        vh = vk / (1 - o["b2"] ** count)
+        new_w[k] = w[k] - o["lr"] * (mh / (jnp.sqrt(vh) + o["eps"])
+                                     + o["weight_decay"] * w[k])
+        new_s[k] = (mk, vk)
+    return new_w, new_s, gn, jax.tree.map(lambda a: a * scale, g)
+
+
+def adamw(w: dict, g: dict, state, count: int, opt: dict):
+    """One AdamW step (``opt``: ``lr``, ``b1``, ``b2``, ``eps``,
+    ``weight_decay``, ``grad_clip``) on the gradient clipped by its global
+    norm; ``state`` None at the first step.  Returns the new weights and
+    state, the global norm before clipping and the clipped gradient.
+    ``w`` and ``state`` are donated."""
+    if state is None:
+        state = {k: (jnp.zeros_like(a), jnp.zeros_like(a))
+                 for k, a in w.items()}
+    return _adamw(w, g, state, jnp.float32(count),
+                  opt=tuple(sorted(opt.items())))

@@ -1,5 +1,8 @@
 """The serving cells: an open-loop generator feeding the gateway live.
 
+``run`` and ``roofline_s`` are the runner that ``bench/run.py`` finds for a
+traffic file of ``"kind": "serve"``.
+
 One ``Session.serve_stream(queue=RequestQueue())`` call serves the whole
 run (it draws the weights once).  A feeder thread feeds it while the main
 thread sits in that call:
@@ -29,13 +32,16 @@ of the whole process shows in every stream).
 from __future__ import annotations
 
 import gc
+import json
 import math
 import threading
 import time
 
 import numpy as np
 
-from common import Window, compile_free_window, host_span
+import common
+import flops
+from common import Window, compile_free_window, host_span, log
 
 
 class TimedTokens(list):
@@ -266,3 +272,79 @@ def check_sample(rec: dict, seed: int, tokens_wanted: int) -> list[dict]:
     k = min(len(done), max(1, math.ceil(tokens_wanted / per)))
     idx = np.random.default_rng(seed + 17).choice(len(done), k, replace=False)
     return [done[i] for i in sorted(idx)]
+
+
+# -- the runner ---------------------------------------------------------------
+def roofline_s(cell, rec: dict, peak: dict) -> float:
+    """Least device seconds the window's prefills and decode rounds need."""
+    m, t, win = cell.dims, cell.traffic, rec["window"]
+    rounds = (win.delta("real_tokens") + win.delta("padded_slot_tokens")) \
+        / t["slots"]
+    pf, bw = peak["bf16_flops_per_s"], peak["hbm_bytes_per_s"]
+    fd, bd = flops.decode(m, rounds, rec["decode_positions"])
+    fp, bp = flops.prefill(m, int(t["prompt_len"]))
+    return (max(fd / pf, bd / bw)
+            + rec["prefills"] * max(fp / pf, bp / bw)) / cell.chips
+
+
+def _mean(xs) -> float | None:
+    return float(sum(xs) / len(xs)) if len(xs) else None
+
+
+def gap_readings(gaps: list) -> dict:
+    return {"mean_logit_gap": _mean(gaps),
+            "max_logit_gap": float(max(gaps)) if gaps else None}
+
+
+def run(cell, args, clock) -> tuple:
+    """One serving run: the records the metric readers take, and the
+    numbers the cell's checks file compares."""
+    reference = cell.reference
+    session = cell.plan().compile()
+    try:
+        rec = ServeRun(cell, args.seconds, bool(args.trace), clock).run(session)
+    finally:
+        session.close()
+    rec["memory_peak_bytes"] = common.memory_peak(cell.chips)
+    # free the program's state before the reference runs on the chip
+    session._gateway = None
+    del session
+    gc.collect()
+    log(f"live_bytes[after the program]: {common.live_bytes()}")
+    t = cell.traffic
+    log(f"window: {rec['window'].seconds:.3f} s, {rec['attempted']} requests "
+        f"due, {rec['completed']} completed, {rec['failed']} failed; "
+        f"gateway {json.dumps(rec['gateway'])}; compiles in window "
+        f"{rec['window'].compiles}; generator late p50 "
+        f"{common.percentile(rec['late_s'], 50, 1e3)} ms")
+    for name, key, ps in (("ttft", "ttft_s", (50, 75, 90, 95, 100)),
+                          ("itl", "itl_s", (50, 90, 95, 99, 99.9, 100))):
+        qs = " ".join(f"p{p:g} {common.percentile(rec[key], p, 1e3)}"
+                      for p in ps)
+        mean = _mean(rec[key])
+        log(f"tails: {name} ms {qs} mean "
+            f"{None if mean is None else mean * 1e3} (n={len(rec[key])})")
+    log(f"stalls: {json.dumps(rec['stalls'])}")
+    log(f"out tokens in the window {rec['out_tokens']}")
+    # correctness: the served tokens against the reference's logits
+    sample = check_sample(rec, cell.seed, int(t["check_tokens"]))
+    t0 = time.perf_counter()
+    w = reference.make_weights(cell.config, cell.program_seed)
+    served, ctl = [], []
+    for r in sample:
+        g, c = reference.served_gaps(cell.config, w, r["prompt"],
+                                     r["tokens"], control=args.control)
+        served += list(g)
+        ctl += [] if c is None else list(c)
+    del w
+    log(f"reference: {len(sample)} requests, {len(served)} served tokens "
+        f"compared in {time.perf_counter() - t0:.1f} s; program "
+        f"{json.dumps(gap_readings(served))}")
+    if args.control:
+        log(f"control: the fp8 reference's tokens in the program's place "
+            f"{json.dumps(gap_readings(ctl))}")
+    short = sum(len(r["tokens"]) != t["gen_len"] + 1
+                for r in rec["requests"] if r["status"] == "done")
+    readings = {**gap_readings(ctl if args.control else served),
+                "short_streams": short, "failed_requests": rec["failed"]}
+    return rec, readings

@@ -17,6 +17,12 @@ TINY = {"hidden_size": 128, "num_attention_heads": 4,
 SERVE = {"kind": "serve", "rate_per_s": 20.0, "prompt_len": 16,
          "gen_len": 8, "slots": 4, "warmup_requests": 6,
          "check_tokens": 24}
+TRAIN = dict(json.loads((REPO / "bench" / "traffic" / "train-2x2.json")
+                        .read_text()), batch=4, seq=32)
+CHECKS = {"serve": {"mean_logit_gap": 1.0, "short_streams": 0,
+                    "failed_requests": 0},
+          "train": json.loads((REPO / "bench" / "checks" /
+                               "qwen2.5-3b.train-2x2.json").read_text())}
 
 
 def tiny_config(name: str) -> dict:
@@ -37,8 +43,9 @@ def make_root(tmp: pathlib.Path, cells: list, *, limits=None) -> pathlib.Path:
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     bench = {k: real[k] for k in ("command", "paths", "run_seconds")}
     bench.update(configs=[], workloads=[], end_to_end=[], per_layer=[])
-    e2e = {"serve": ["itl_p95_ms"]}
-    per = {"serve": ["round_ms.chat", "mfu.chat", "device_idle.chat"]}
+    e2e = {"serve": ["itl_p95_ms"], "train": ["train_tok_s"]}
+    per = {"serve": ["round_ms.chat", "mfu.chat", "device_idle.chat"],
+           "train": ["mfu.train", "device_idle.train", "comm_ms.train"]}
     byname = {e["name"]: e for e in real["end_to_end"] + real["per_layer"]}
     names = [w for w, *_ in cells]
     for e in real["end_to_end"]:
@@ -50,9 +57,9 @@ def make_root(tmp: pathlib.Path, cells: list, *, limits=None) -> pathlib.Path:
             json.dumps(cfg))
         (root / "bench" / "traffic" / f"{tname}.json").write_text(
             json.dumps(traffic))
-        lim = (limits or {}).get(w) or {"mean_logit_gap": 1.0,
-                                        "short_streams": 0,
-                                        "failed_requests": 0}
+        lim = (limits or {}).get(w)
+        if lim is None:
+            lim = CHECKS[traffic["kind"]]
         (root / "bench" / "checks" / f"{w}.json").write_text(json.dumps(lim))
         bench["configs"].append({"name": cname, "source": "test",
                                  "file": f"bench/configs/{cname}.json",
@@ -61,7 +68,7 @@ def make_root(tmp: pathlib.Path, cells: list, *, limits=None) -> pathlib.Path:
                                    "traffic": tname, "chips": chips,
                                    "why": "test"})
         for key, table in (("end_to_end", e2e), ("per_layer", per)):
-            for n in table[traffic["kind"]]:
+            for n in table.get(traffic["kind"], []):
                 have = {e["name"]: e for e in bench[key]}
                 if n not in have:
                     have[n] = dict(byname[n], workloads=[])

@@ -69,3 +69,17 @@ def test_prompts_repeat_for_a_seed():
                                                        512))
     assert a.shape == (5, 16) and a.dtype == np.int32
     assert 0 <= a.min() and a.max() < 512
+
+
+def test_train_counts_by_hand():
+    m = dims("qwen2.5-3b")
+    # q 2048x16x128, k and v 2048x2x128, o 16x128x2048, MLP 3 x 2048x11008
+    per_layer = 4_194_304 + 2 * 524_288 + 4_194_304 + 3 * 22_544_384
+    assert flops.matmul_params(m) == per_layer == 77_070_336
+    head = 2048 * 151_936
+    # 2 sequences of 8 tokens: 6 flops per weight and token, and each
+    # query sees 1..8 keys, 4 * hd flops per head and layer, three times
+    L = m["L"]
+    f = flops.train(m, 2, 8)
+    assert f == 6 * (L * per_layer + head) * 16 \
+        + 3 * 4 * L * 16 * 128 * (2 * 36)

@@ -120,3 +120,69 @@ def test_refuses_without_the_program(tmp_path):
                        timeout=300)
     assert p.returncode != 0
     assert p.stdout.strip() == ""
+
+
+ECHO = '''"""A made-up traffic kind: an empty window and one answer."""
+import time
+
+from common import compile_free_window
+
+
+def run(cell, args, clock):
+    with compile_free_window(clock, bool(args.trace)) as win:
+        time.sleep(0.05)
+    rec = {"window": win, "attempted": 1, "failed": 0,
+           "memory_peak_bytes": 0}
+    return rec, {"answer_gap": cell.reference.answer(cell.config)
+                 - cell.config["answer"]}
+
+
+def roofline_s(cell, rec, peak):
+    return 0.0
+'''
+TOYREF = '''"""A made-up reference: the answer is twice the configuration's half."""
+
+
+def dims(cfg):
+    return {"V": 1}
+
+
+def make_weights(cfg, seed, dtype=None):
+    return {}
+
+
+def plan_overrides(cfg):
+    return {}
+
+
+def answer(cfg):
+    return 2 * cfg["half"]
+'''
+
+
+def test_kind_and_reference_found_by_name(tmp_path):
+    """A traffic kind and a reference that ``run.py`` has never heard of,
+    each a new file under a checkout root, run a cell with no edit to it."""
+    root = make_root(tmp_path, [("toy.echo", {"reference": "toyref",
+                                              "half": 3, "answer": 6},
+                                 {"kind": "echo"}, 1)],
+                     limits={"toy.echo": {"answer_gap": 0}})
+    (root / "bench" / "echo.py").write_text(ECHO)
+    (root / "bench" / "toyref.py").write_text(TOYREF)
+    assert (root / "bench" / "run.py").read_text() == \
+        (REPO / "bench" / "run.py").read_text()
+    assert "echo" not in (root / "bench" / "run.py").read_text()
+    rc, res, err = run_cell(root, "toy.echo", seconds=0.1)
+    assert rc == 0, err[-3000:]
+    assert res["correct"] is True
+    assert res["checks"] == {"answer_gap": {"value": 0, "limit": 0}}
+    assert set(res["metrics"]) == {"setup_s"}
+
+
+def test_unknown_kind_names_the_file(tmp_path):
+    root = make_root(tmp_path, [("toy.none", tiny_config("qwen3-4b"),
+                                 {"kind": "nosuchkind"}, 1)],
+                     limits={"toy.none": {}})
+    rc, res, err = run_cell(root, "toy.none", seconds=0.1)
+    assert rc != 0 and res is None
+    assert "bench/nosuchkind.py does not exist" in err

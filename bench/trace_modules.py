@@ -16,9 +16,8 @@ profiler trace (``.xplane.pb``).
                  reads the queue; the clocks' offset is at or below the
                  smallest difference.
 
-``trace_reduce.reduce_profile`` does not return these yet; a benchmark
-change would add ``modules(pd, window)`` and ``clock_skew_us(pd)`` to its
-result.
+``trace_reduce.reduce_profile`` returns both, the skew as its median and
+its least difference.
 """
 from __future__ import annotations
 
@@ -83,10 +82,11 @@ def modules(pd, win: tuple) -> dict:
             for k, v in secs.most_common()}
 
 
-def skew_us(device_runs: list, launches: list) -> float | None:
-    """Median of device start minus launch start, in us.  Both lists hold
-    (start_ns, run_id or None); runs are matched by run_id where every
-    entry has one, else in order of their starts."""
+def skew_us(device_runs: list, launches: list,
+            stat=np.median) -> float | None:
+    """``stat`` (the median) of device start minus launch start, in us.
+    Both lists hold (start_ns, run_id or None); runs are matched by run_id
+    where every entry has one, else in order of their starts."""
     if all(r is not None for _, r in device_runs + launches):
         first: dict = {}
         for t, r in launches:
@@ -95,14 +95,19 @@ def skew_us(device_runs: list, launches: list) -> float | None:
     else:
         diffs = [d - h for (d, _), (h, _) in zip(sorted(device_runs),
                                                   sorted(launches))]
-    return float(np.median(diffs)) / 1e3 if diffs else None
+    return float(stat(diffs)) / 1e3 if diffs else None
 
 
-def clock_skew_us(pd) -> float | None:
-    """The trace's host-device clock skew (module docstring); None where
-    it has no device plane or no launch event."""
-    device_runs = [(a, r) for plane in _device_planes(pd)
-                   for a, _, _, r in _runs(plane)]
+def device_runs(pd) -> list:
+    """(start_ns, run_id or None) of every run on the device planes."""
+    return [(a, r) for plane in _device_planes(pd)
+            for a, _, _, r in _runs(plane)]
+
+
+def clock_skew_us(pd, stat=np.median) -> float | None:
+    """The trace's host-device clock skew (module docstring), by ``stat``
+    over the runs; None where it has no device plane or no launch
+    event."""
     launches = [(e.start_ns, dict(e.stats).get("run_id"))
                 for e in _host_events(pd) if e.name == LAUNCH_EVENT]
-    return skew_us(device_runs, launches)
+    return skew_us(device_runs(pd), launches, stat)
