@@ -2,12 +2,13 @@
 from .base import ArchConfig  # noqa: F401
 
 from . import (chameleon_34b, granite_moe_1b, phi35_moe, xlstm_350m,
-               whisper_medium, mistral_nemo_12b, qwen3_4b, qwen25_3b,
-               phi3_mini, zamba2_27b)
+               whisper_medium, mistral_nemo_12b, moonlight_16b_a3b, qwen3_4b,
+               qwen25_3b, phi3_mini, zamba2_27b)
 
 REGISTRY = {m.CONFIG.name: m.CONFIG for m in (
     chameleon_34b, granite_moe_1b, phi35_moe, xlstm_350m, whisper_medium,
-    mistral_nemo_12b, qwen3_4b, qwen25_3b, phi3_mini, zamba2_27b)}
+    mistral_nemo_12b, moonlight_16b_a3b, qwen3_4b, qwen25_3b, phi3_mini,
+    zamba2_27b)}
 
 ARCH_IDS = sorted(REGISTRY)
 

@@ -26,7 +26,7 @@ from . import fusion, overlap
 from .granularity import GrainPolicy
 from .sharding import (ShardingRules, act_hook, default_rules, init_params,
                        param_shardings, param_structs, spec_for)
-from ..models.model import build_model
+from ..models.model import build_model, reduce_stats
 from ..optim.optimizers import OptConfig
 from ..optim import optimizers as optim
 
@@ -220,31 +220,44 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
                              is_leaf=lambda x: hasattr(x, "dims"))
 
     def loss_fn(params, batch):
-        return model.loss(params, batch)
+        # the MoE counters ride out of the forward beside the loss
+        with model.recording() as stats:
+            loss = model.loss(params, batch)
+        return loss, {k: v for k, v in stats.items() if k.startswith("moe_")}
 
     def grads_of(params, batch):
         if strategy.grad_accum <= 1:
-            return jax.value_and_grad(loss_fn)(params, batch)
+            return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
         k = strategy.grad_accum
         micro = jax.tree.map(
             lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]), batch)
 
         def acc(carry, mb):
-            l, g = jax.value_and_grad(loss_fn)(params, mb)
-            return (carry[0] + l / k,
+            (l, st), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
+            st = reduce_stats(jax.tree.map(lambda *v: jnp.stack(v),
+                                           carry[0][1], st))
+            return ((carry[0][0] + l / k, st),
                     jax.tree.map(lambda a, b: a + b / k, carry[1], g)), None
         zero_g = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32),
                               structs)
-        (l, g), _ = jax.lax.scan(acc, (jnp.zeros((), jnp.float32), zero_g),
-                                 micro)
+        zero_st = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(loss_fn, params,
+                           jax.tree.map(lambda x: x[0], micro))[1])
+        (l, g), _ = jax.lax.scan(
+            acc, ((jnp.zeros((), jnp.float32), zero_st), zero_g), micro)
         return l, g
 
     def train_step(params, opt_state, batch):
         # inside shard_map the batch dim is already local: constrain only
         # auto-axis (model) placements; seq joins under sequence parallelism
         with act_hook(mesh, rules.with_overrides(batch=None)):
-            loss, grads = grads_of(params, batch)
+            (loss, counts), grads = grads_of(params, batch)
         loss = jax.lax.pmean(loss, axes) if axes else loss
+        if axes:
+            counts = {k: (jax.lax.pmax if k == "moe_max_load"
+                          else jax.lax.psum)(v, axes)
+                      for k, v in counts.items()}
         if strategy.name == "zero1":
             params, opt_state, m = overlap.zero1_update(
                 grads, opt_state, params, oc, axes, scatter_mask)
@@ -264,7 +277,7 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
             else:
                 grads_r = grads
             params, opt_state, m = optim.update(grads_r, opt_state, params, oc)
-        metrics = {"loss": loss, "grad_norm": m["grad_norm"]}
+        metrics = {"loss": loss, "grad_norm": m["grad_norm"], **counts}
         return metrics, params, opt_state
 
     if axes:
@@ -307,8 +320,8 @@ def make_train_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
         opt_sh = param_shardings(f32_specs, mesh, rules)
 
     b_shard = batch_shardings(cfg, mesh, shape)
-    metrics_sh = {"loss": NamedSharding(mesh, P()),
-                  "grad_norm": NamedSharding(mesh, P())}
+    # every metric is a replicated scalar (a prefix of the metrics dict)
+    metrics_sh = NamedSharding(mesh, P())
     jitted = jax.jit(fn, donate_argnums=(0, 1),
                      in_shardings=(p_shard, opt_sh, b_shard),
                      out_shardings=(metrics_sh, p_shard, opt_sh))

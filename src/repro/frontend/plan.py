@@ -434,6 +434,11 @@ class Session:
         return self._train_step
 
     def _serve_steps_for(self, prompt_len: int, gen_len: int, slots: int):
+        if self.cfg.mla:
+            raise ValueError(
+                f"{self.cfg.name}: serving latent attention (MLA) is not "
+                f"supported - there is no latent decode cache; MLA models "
+                f"train only (Session.train)")
         key = (prompt_len, gen_len, slots)
         if key not in self._serve_steps:
             cache_len = prompt_len + gen_len
@@ -1173,6 +1178,8 @@ def cell_is_applicable(cfg, shape_name: str) -> tuple[bool, str]:
     if shape_name == "long_500k" and not cfg.subquadratic:
         return False, ("long_500k needs sub-quadratic attention "
                        "(skip noted in DESIGN.md)")
+    if cfg.mla and SHAPES[shape_name]["kind"] != "train":
+        return False, "latent attention (MLA) has no decode cache"
     return True, ""
 
 

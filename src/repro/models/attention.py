@@ -8,6 +8,11 @@ Implementations:
   * Pallas kernel (kernels/flash_attention.py) plugs in through the same
     signature on TPU via kernels/ops.py.
 
+Latent attention (MLA, DeepSeek-V2/V3) has its own projections
+(``mla_specs``/``mla_layer``) and attends through the same ``attend``: its
+q and k are [nope | rope] per head and wider than v.  It is wired for
+training and full forwards only; a latent decode cache does not exist yet.
+
 Decode attends a single new token against a KV cache; for long contexts the
 cache's sequence dim may be sharded (tiling plan "kv_seq"), in which case the
 softmax reduction spans shards - XLA partitions those reductions, and the
@@ -46,6 +51,59 @@ def attn_specs(d: int, n_heads: int, n_kv: int, head_dim: int, *,
         sp["q_norm"] = ParamSpec((head_dim,), ("head_dim",), init="ones")
         sp["k_norm"] = ParamSpec((head_dim,), ("head_dim",), init="ones")
     return sp
+
+
+# Hugging Face's DeepseekV3 builds kv_a_layernorm with its RMSNorm's default
+# eps (1e-6), not the model's rms_norm_eps
+MLA_KV_NORM_EPS = 1e-6
+
+
+def mla_specs(d: int, n_heads: int, kv_rank: int, nope: int, rope: int,
+              v_dim: int) -> dict:
+    """Latent attention without a q LoRA: ``wq`` [d, H, nope+rope];
+    ``wkv_a`` [d, kv_rank+rope] (the latent, then the shared rope key);
+    ``kv_norm`` on the latent; ``wkv_b`` [kv_rank, H, nope+v] (each head's
+    k_nope, then its v); ``wo`` [H, v, d]."""
+    return {
+        "wq": ParamSpec((d, n_heads, nope + rope),
+                        ("embed", "heads", "head_dim")),
+        "wkv_a": ParamSpec((d, kv_rank + rope), ("embed", None)),
+        "kv_norm": ParamSpec((kv_rank,), (None,), init="ones"),
+        "wkv_b": ParamSpec((kv_rank, n_heads, nope + v_dim),
+                           (None, "heads", "head_dim")),
+        "wo": ParamSpec((n_heads, v_dim, d), ("heads", "head_dim", "embed")),
+    }
+
+
+def mla_layer(x, p, cfg, *, impl: str = "chunked"):
+    """x: [B, S, D] -> [B, S, D], causal latent attention.
+
+    q_nope|q_pe = x Wq; c|k_pe = x Wkv_a; c = rms(c) * kv_norm;
+    k_nope|v = c Wkv_b; q_pe and k_pe (one key, shared by every head) are
+    rotated; q = [q_nope | q_pe], k = [k_nope | k_pe], scale 1/sqrt(nope +
+    rope).  RoPE pairs dimension i with i + rope/2 of the rope part
+    (rotate-half); Hugging Face's DeepseekV3 stores those dimensions
+    interleaved and de-interleaves them before the same rotation, which
+    is a fixed permutation of the rope columns of Wq and Wkv_a."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla"):
+        pos = jnp.arange(x.shape[1])[None, :]
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+        kv = x @ p["wkv_a"].astype(dt)
+        c = layers.rms_norm(kv[..., :r], p["kv_norm"], MLA_KV_NORM_EPS)
+        k_pe = layers.apply_rope(kv[..., None, r:], pos, cfg.rope_theta)
+        kvb = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"].astype(dt))
+        k_nope, v = kvb[..., :nope], kvb[..., nope:]
+        q = jnp.concatenate(
+            [q[..., :nope],
+             layers.apply_rope(q[..., nope:], pos, cfg.rope_theta)], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1]
+                                      + (k_pe.shape[-1],))], -1)
+        o = attend(q, k, v, impl=impl, causal=True, q_chunk=cfg.q_chunk,
+                   kv_chunk=cfg.kv_chunk)
+        return jnp.einsum("bqhk,hkd->bqd", o, p["wo"].astype(dt))
 
 
 def project_qkv(x, p, *, positions=None, rope_theta: float = 10000.0,
@@ -140,7 +198,7 @@ def attend_chunked(q, k, v, *, causal: bool = True,
     """
     from ..core.sharding import act_constrain
     B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
+    Sk, vd = k.shape[1], v.shape[-1]       # v may be narrower than q, k
     scale = scale or (1.0 / math.sqrt(hd))
     k = _expand_kv(k, H)
     v = _expand_kv(v, H)
@@ -162,7 +220,7 @@ def attend_chunked(q, k, v, *, causal: bool = True,
     nq, nk = Sq // q_chunk, Sk // kv_chunk
 
     kb = jnp.moveaxis(k.reshape(B, nk, kv_chunk, H, hd), 1, 0)
-    vb = jnp.moveaxis(v.reshape(B, nk, kv_chunk, H, hd), 1, 0)
+    vb = jnp.moveaxis(v.reshape(B, nk, kv_chunk, H, vd), 1, 0)
 
     outs = []
     for iq in range(nq):
@@ -170,12 +228,12 @@ def attend_chunked(q, k, v, *, causal: bool = True,
         blocks = _row_blocks(iq, nk, q_chunk, kv_chunk, q_offset, causal,
                              window)
         if not blocks:
-            outs.append(jnp.zeros((B, q_chunk, H, hd), q.dtype))
+            outs.append(jnp.zeros((B, q_chunk, H, vd), q.dtype))
             continue
         lo, hi = blocks[0], blocks[-1]       # always a contiguous range
 
         def block(carry, inputs, iq=iq):
-            o, m, l = carry                  # [B,H,qc,hd],[B,H,qc],[B,H,qc]
+            o, m, l = carry                  # [B,H,qc,vd],[B,H,qc],[B,H,qc]
             kj, vj, ik = inputs
             lg = jnp.einsum("bqhk,bshk->bhqs", qi, kj
                             ).astype(jnp.float32) * scale
@@ -197,7 +255,7 @@ def attend_chunked(q, k, v, *, causal: bool = True,
                 "bhqs,bshk->bhqk", p.astype(qi.dtype), vj).astype(jnp.float32)
             return (o, m_new, l), None
 
-        o0 = jnp.zeros((B, H, q_chunk, hd), jnp.float32)
+        o0 = jnp.zeros((B, H, q_chunk, vd), jnp.float32)
         m0 = jnp.full((B, H, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, q_chunk), jnp.float32)
         body = jax.checkpoint(block) if remat_chunks else block

@@ -22,15 +22,24 @@ from . import attention, layers, moe, ssm
 # Transformer block (attention + MLP or MoE), optional cross-attention
 # ---------------------------------------------------------------------------
 def tblock_specs(cfg, *, cross: bool = False, use_moe: bool = False) -> dict:
+    if cfg.mla:
+        attn = attention.mla_specs(cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                                   cfg.qk_nope_dim, cfg.qk_rope_dim,
+                                   cfg.v_head_dim)
+    else:
+        attn = attention.attn_specs(
+            cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+            qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm)
     sp = {
         "ln_attn": layers.norm_specs(cfg.d_model, cfg.norm),
-        "attn": attention.attn_specs(
-            cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
-            qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm),
+        "attn": attn,
         "ln_mlp": layers.norm_specs(cfg.d_model, cfg.norm),
     }
     if use_moe:
-        sp["moe"] = moe.moe_specs(cfg.d_model, cfg.d_ff, cfg.n_experts)
+        sp["moe"] = moe.moe_specs(
+            cfg.d_model, cfg.moe_d_ff, cfg.n_experts, n_held=cfg.experts_held,
+            shared_ff=cfg.n_shared_experts * cfg.moe_d_ff,
+            router_bias=cfg.router == "sigmoid")
     else:
         sp["mlp"] = layers.mlp_specs(cfg.d_model, cfg.d_ff, cfg.mlp_kind)
     if cross:
@@ -43,20 +52,33 @@ def tblock_specs(cfg, *, cross: bool = False, use_moe: bool = False) -> dict:
 
 def tblock_apply(x, p, cfg, *, impl: str = "chunked", causal: bool = True,
                  positions=None, enc_kv=None):
-    h = layers.apply_norm(x, p["ln_attn"], cfg.norm)
-    x = x + attention.attn_layer(h, p["attn"], cfg, impl=impl,
-                                 positions=positions, causal=causal)
+    """-> (x, stats): the MoE layer's (``moe.apply_moe``), or
+    ``{"aux": 0}``."""
+    eps = cfg.norm_eps
+    h = layers.apply_norm(x, p["ln_attn"], cfg.norm, eps)
+    if cfg.mla:
+        x = x + attention.mla_layer(h, p["attn"], cfg, impl=impl)
+    else:
+        x = x + attention.attn_layer(h, p["attn"], cfg, impl=impl,
+                                     positions=positions, causal=causal)
     if "cross" in p:
-        h = layers.apply_norm(x, p["ln_cross"], cfg.norm)
+        h = layers.apply_norm(x, p["ln_cross"], cfg.norm, eps)
         x = x + attention.attn_layer(h, p["cross"], cfg, impl=impl,
                                      kv_override=enc_kv)
-    h = layers.apply_norm(x, p["ln_mlp"], cfg.norm)
+    h = layers.apply_norm(x, p["ln_mlp"], cfg.norm, eps)
     if "moe" in p:
-        y, aux = moe.apply_moe(h, p["moe"], top_k=cfg.top_k,
-                               group_size=cfg.moe_group,
-                               dispatch=cfg.moe_dispatch)
-        return x + y, aux
-    return x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind), jnp.zeros((), jnp.float32)
+        y, stats = _moe(h, p["moe"], cfg)
+        return x + y, stats
+    return (x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind),
+            {"aux": jnp.zeros((), jnp.float32)})
+
+
+def _moe(h, p, cfg):
+    return moe.apply_moe(h, p, top_k=cfg.top_k, group_size=cfg.moe_group,
+                         cap_factor=cfg.capacity_factor,
+                         dispatch=cfg.moe_dispatch, scoring=cfg.router,
+                         routed_scale=cfg.routed_scale,
+                         expert_offset=cfg.expert_offset)
 
 
 def kv_cache_specs(cfg, batch: int, seq: int, n_layers: Optional[int] = None,
@@ -112,10 +134,7 @@ def tblock_decode_attend(x, p, cfg, q, kc, vc, pos, *, enc_kv=None):
                            p["cross"]["wo"].astype(x.dtype))
     h = layers.apply_norm(x, p["ln_mlp"], cfg.norm)
     if "moe" in p:
-        y, _ = moe.apply_moe(h, p["moe"], top_k=cfg.top_k,
-                             group_size=cfg.moe_group,
-                             dispatch=cfg.moe_dispatch)
-        return x + y
+        return x + _moe(h, p["moe"], cfg)[0]
     return x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind)
 
 
@@ -267,10 +286,7 @@ def tblock_prefill(x, p, cfg, cache_len: int, *, impl: str = "chunked",
         cache["ck"], cache["cv"] = enc_kv
     h = layers.apply_norm(x, p["ln_mlp"], cfg.norm)
     if "moe" in p:
-        y, aux = moe.apply_moe(h, p["moe"], top_k=cfg.top_k,
-                               group_size=cfg.moe_group,
-                               dispatch=cfg.moe_dispatch)
-        x = x + y
+        x = x + _moe(h, p["moe"], cfg)[0]
     else:
         x = x + layers.apply_mlp(h, p["mlp"], cfg.mlp_kind)
     return x, cache

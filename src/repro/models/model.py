@@ -9,12 +9,14 @@ Contract (used by core.steps, launch.dryrun, examples):
   m.specs()                                  ParamSpec tree
   m.apply(params, batch)                  -> (logits, aux)     train fwd
   m.loss(params, batch)                   -> scalar
+  with m.recording() as stats: m.loss(...)   the forward's layer stats
   m.cache_specs(batch, cache_len)            ParamSpec tree (zeros init)
   m.prefill(params, batch, cache_len)     -> (last logits, cache)
   m.decode_step(params, cache, batch, pos)-> (logits, cache)
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -36,10 +38,39 @@ def _maybe_remat(fn, enable: bool):
     return jax.checkpoint(fn, prevent_cse=False) if enable else fn
 
 
+def reduce_stats(stacked: dict) -> dict:
+    """Counters stacked along a leading dim (by a layer scan, or of
+    several forwards) -> one: sums, and the largest ``moe_max_load``."""
+    return {k: v.max(0) if k == "moe_max_load" else v.sum(0)
+            for k, v in stacked.items()}
+
+
+class _Recorder:
+    """``with model.recording() as stats: model.loss(...)``: the step that
+    traces the loss gets the forward's layer statistics (``aux``, and the
+    MoE counters ``moe_assigned``/``moe_max_load``) as values of its own
+    trace, while ``loss`` itself stays a scalar function."""
+
+    _sink = None
+
+    @contextlib.contextmanager
+    def recording(self):
+        prev, self._sink = self._sink, {}
+        try:
+            yield self._sink
+        finally:
+            self._sink = prev
+
+    def _record(self, stats: dict):
+        if self._sink is not None:
+            self._sink.clear()
+            self._sink.update(stats)
+
+
 # ===========================================================================
 # Decoder-only LM (dense / moe / xlstm / zamba)
 # ===========================================================================
-class LM:
+class LM(_Recorder):
     def __init__(self, cfg):
         self.cfg = cfg
 
@@ -53,8 +84,12 @@ class LM:
         }
         fam = cfg.family
         if fam in ("dense", "moe"):
+            n_dense = cfg.first_dense if fam == "moe" else 0
+            if n_dense:
+                sp["dense"] = stack_specs(blocks.tblock_specs(cfg), n_dense)
             sp["stack"] = stack_specs(
-                blocks.tblock_specs(cfg, use_moe=(fam == "moe")), cfg.n_layers)
+                blocks.tblock_specs(cfg, use_moe=(fam == "moe")),
+                cfg.n_layers - n_dense)
         elif fam == "xlstm":
             groups = cfg.n_layers // cfg.slstm_every
             per = cfg.slstm_every - 1
@@ -82,7 +117,9 @@ class LM:
 
     # -- forward ---------------------------------------------------------------
     def _backbone(self, params, x):
-        """x: [B, S, D] -> (x, aux)."""
+        """x: [B, S, D] -> (x, stats): ``aux`` and, for MoE layers that
+        count them, ``moe_assigned`` (summed) and ``moe_max_load``
+        (largest)."""
         cfg = self.cfg
         fam = cfg.family
 
@@ -90,15 +127,20 @@ class LM:
             def body(carry, p):
                 h, aux = carry
                 h = act_constrain(h, ("batch", "seq", "embed"))
-                h, a = blocks.tblock_apply(h, p, cfg)
+                h, st = blocks.tblock_apply(h, p, cfg)
                 # constrain the OUTPUT too: it is what scan saves for the
                 # backward pass (the activation-checkpoint stack)
                 h = act_constrain(h, ("batch", "seq", "embed"))
-                return (h, aux + a), None
+                return (h, aux + st.pop("aux")), st
             body = _maybe_remat(body, cfg.remat)
-            (x, aux), _ = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)), params["stack"])
-            return x, aux
+            aux, counts = jnp.zeros((), jnp.float32), {}
+            # the leading dense layers, then the uniform stack; only MoE
+            # layers give counters
+            for name in ("dense", "stack"):
+                if name in params:
+                    (x, aux), ys = jax.lax.scan(body, (x, aux), params[name])
+                    counts.update(reduce_stats(ys))
+            return x, {"aux": aux, **counts}
 
         if fam == "xlstm":
             def m_body(h, p):
@@ -111,7 +153,7 @@ class LM:
                 h = blocks.slstm_block_apply(h, gp["s"], cfg)
                 return h, None
             x, _ = jax.lax.scan(g_body, x, params["stack"])
-            return x, jnp.zeros((), jnp.float32)
+            return x, {"aux": jnp.zeros((), jnp.float32)}
 
         if fam == "zamba":
             shared = params["stack"]["shared"]
@@ -129,16 +171,17 @@ class LM:
                 return h, None
             g_fn = _maybe_remat(g_body, cfg.remat)
             x, _ = jax.lax.scan(g_fn, x, params["stack"]["mamba"])
-            return x, jnp.zeros((), jnp.float32)
+            return x, {"aux": jnp.zeros((), jnp.float32)}
 
         raise ValueError(fam)
 
     def apply(self, params, batch):
         cfg = self.cfg
         x = layers.embed(batch["tokens"], params["embed"]).astype(cfg.c_dtype)
-        x, aux = self._backbone(params, x)
-        x = layers.apply_norm(x, params["ln_f"], cfg.norm)
-        return layers.logits(x, params["unembed"]), aux
+        x, stats = self._backbone(params, x)
+        self._record(stats)
+        x = layers.apply_norm(x, params["ln_f"], cfg.norm, cfg.norm_eps)
+        return layers.logits(x, params["unembed"]), stats["aux"]
 
     def loss(self, params, batch):
         lg, aux = self.apply(params, batch)
@@ -147,9 +190,18 @@ class LM:
             + self.cfg.aux_weight * aux
 
     # -- decode cache -----------------------------------------------------------
+    def _check_servable(self):
+        cfg = self.cfg
+        if cfg.mla or cfg.first_dense:
+            raise ValueError(
+                f"{cfg.name}: serving latent attention (MLA) or leading "
+                f"dense layers is not supported: there is no latent decode "
+                f"cache; such models train and run full forwards only")
+
     def cache_specs(self, batch: int, cache_len: int) -> dict:
         cfg = self.cfg
         fam = cfg.family
+        self._check_servable()
         if fam in ("dense", "moe"):
             return blocks.kv_cache_specs(cfg, batch, cache_len,
                                          prefix=(cfg.n_layers,))
@@ -174,6 +226,7 @@ class LM:
     def prefill(self, params, batch, cache_len: int):
         cfg = self.cfg
         fam = cfg.family
+        self._check_servable()
         x = layers.embed(batch["tokens"], params["embed"]).astype(cfg.c_dtype)
 
         if fam in ("dense", "moe"):
@@ -218,6 +271,7 @@ class LM:
         SSM/xLSTM blocks ignore pos, attention blocks broadcast it)."""
         cfg = self.cfg
         fam = cfg.family
+        self._check_servable()
         x = layers.embed(batch["tokens"], params["embed"]).astype(cfg.c_dtype)
 
         if fam in ("dense", "moe"):
@@ -274,7 +328,7 @@ class LM:
 # ===========================================================================
 # Encoder-decoder (whisper-style; frontend is a stub: precomputed frames)
 # ===========================================================================
-class EncDec:
+class EncDec(_Recorder):
     def __init__(self, cfg):
         self.cfg = cfg
 

@@ -28,6 +28,20 @@ def test_chunked_matches_full(B, S, H, KV, hd, window, causal):
                                    rtol=1e-4, atol=1e-5)
 
 
+def test_chunked_matches_full_with_narrower_v():
+    """Latent attention's shapes: q and k wider than v (192 and 128 per
+    head in Moonlight)."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(ks[0], (2, 128, 4, 24))
+    k = jax.random.normal(ks[1], (2, 128, 4, 24))
+    v = jax.random.normal(ks[2], (2, 128, 4, 16))
+    full = attention.attend_full(q, k, v)
+    ch = attention.attend_chunked(q, k, v, q_chunk=32, kv_chunk=64)
+    assert ch.shape == (2, 128, 4, 16)
+    np.testing.assert_allclose(np.asarray(ch), np.asarray(full),
+                               rtol=1e-4, atol=1e-5)
+
+
 def test_chunked_gradients_match_full():
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (1, 64, 2, 16))

@@ -74,7 +74,7 @@ def test_decode_step_matches_full_forward(arch):
 
 
 def test_registry_complete():
-    assert len(REGISTRY) == 10
+    assert len(REGISTRY) == 11
     for name, cfg in REGISTRY.items():
         tot, act = cfg.n_params()
         assert tot > 0 and act > 0 and act <= tot * (1 + 9 / 6 + 1e-6)
@@ -85,7 +85,8 @@ def test_param_counts_match_public_sizes():
     expect = {"chameleon-34b": 34e9, "phi3.5-moe-42b-a6.6b": 42e9,
               "mistral-nemo-12b": 12e9, "phi3-mini-3.8b": 3.8e9,
               "qwen3-4b": 4e9, "zamba2-2.7b": 2.7e9,
-              "whisper-medium": 0.76e9, "granite-moe-1b-a400m": 1.3e9}
+              "whisper-medium": 0.76e9, "granite-moe-1b-a400m": 1.3e9,
+              "moonlight-16b-a3b": 16e9}
     for name, want in expect.items():
         tot, _ = REGISTRY[name].n_params()
         assert abs(tot - want) / want < 0.20, (name, tot, want)

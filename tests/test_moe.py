@@ -1,4 +1,5 @@
-"""MoE routing: dispatch-engine equivalence, capacity semantics, EP math."""
+"""MoE routing: dispatch-engine equivalence, capacity semantics (einsum),
+the dropless sort engine, sigmoid selection with a correction bias."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,18 +13,19 @@ def _params(d=32, ff=64, E=4, key=jax.random.PRNGKey(0)):
 
 
 def test_sort_and_einsum_dispatch_agree_without_drops():
-    """With capacity ample enough that nothing drops, both engines compute
-    the same function."""
+    """With capacity ample enough that einsum drops nothing, it computes
+    what the dropless sort engine does; sort counts every assignment."""
     d, ff, E, k = 32, 64, 4, 2
     p = _params(d, ff, E)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, d)) * 0.5
-    y1, a1 = moe.apply_moe(x, p, top_k=k, group_size=32, cap_factor=8.0,
+    y1, s1 = moe.apply_moe(x, p, top_k=k, group_size=32, cap_factor=8.0,
                            dispatch="einsum")
-    y2, a2 = moe.apply_moe(x, p, top_k=k, group_size=32, cap_factor=8.0,
+    y2, s2 = moe.apply_moe(x, p, top_k=k, group_size=32, cap_factor=8.0,
                            dispatch="sort")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-3,
                                atol=2e-4)
-    np.testing.assert_allclose(float(a1), float(a2), rtol=1e-5)
+    np.testing.assert_allclose(float(s1["aux"]), float(s2["aux"]), rtol=1e-5)
+    assert int(s2["moe_assigned"]) == 2 * 16 * k
 
 
 def test_dense_equivalence_with_full_capacity_topE():
@@ -50,11 +52,11 @@ def test_capacity_drops_tokens_not_crash():
     p = _params(d, ff, E, jax.random.PRNGKey(4))
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, d))
     # capacity factor tiny -> most tokens dropped, output finite & small
-    y, aux = moe.apply_moe(x, p, top_k=2, group_size=64, cap_factor=0.1,
-                           dispatch="einsum")
+    y, st = moe.apply_moe(x, p, top_k=2, group_size=64, cap_factor=0.1,
+                          dispatch="einsum")
     assert bool(jnp.isfinite(y).all())
     assert float(jnp.abs(y).mean()) < float(jnp.abs(x).mean()) * 10
-    assert np.isfinite(float(aux))
+    assert np.isfinite(float(st["aux"]))
 
 
 def test_capacity_rounding():
@@ -88,3 +90,48 @@ def test_aux_loss_penalizes_imbalance():
     _, _, aux_c = moe.router_probs(x, w_collapse, 1)
     assert float(aux_c) > float(aux_b) * 1.5
     assert float(aux_c) > 0.9 * E  # collapsed ~ E
+
+
+def _dense_expert(x, p, e):
+    """Expert ``e``'s SwiGLU on every row of x [T, d]."""
+    h = jax.nn.silu(x @ p["w_gate"][e]) * (x @ p["w_up"][e])
+    return h @ p["w_down"][e]
+
+
+def test_sort_dispatch_is_dropless_when_every_token_picks_one_expert():
+    """Every token routes to expert 0: the einsum engine's capacity (1.25
+    of an even share) drops most of them; the sort engine drops none."""
+    d, ff, E, T = 16, 32, 4, 64
+    p = _params(d, ff, E, jax.random.PRNGKey(9))
+    p["router"] = jnp.zeros((d, E)).at[:, 0].set(10.0)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(10), (1, T, d))) + 0.1
+    want = _dense_expert(x[0], p, 0)              # weight 1 after top-1 norm
+    y, st = moe.apply_moe(x, p, top_k=1, dispatch="sort")
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert int(st["moe_assigned"]) == T and int(st["moe_max_load"]) == T
+    y_cap, _ = moe.apply_moe(x, p, top_k=1, group_size=T, cap_factor=1.25,
+                             dispatch="einsum")
+    lost = np.all(np.asarray(y_cap[0]) == 0, axis=-1)
+    assert lost.sum() >= T // 2                   # capacity dropped them
+
+
+def test_sigmoid_bias_changes_the_choice_not_the_weights():
+    """A correction bias moves which experts are chosen; their weights are
+    still the unbiased sigmoid scores, normalised over the chosen and
+    scaled."""
+    d, E, k, T = 16, 8, 2, 32
+    x = jax.random.normal(jax.random.PRNGKey(11), (T, d))
+    w = jax.random.normal(jax.random.PRNGKey(12), (d, E)) * 0.3
+    p = {"router": w, "router_bias": jnp.zeros((E,))}
+    w0, ids0, _ = moe.route(x, p, k, "sigmoid", 2.5)
+    bias = jnp.zeros((E,)).at[E - 1].set(10.0)    # expert E-1 always wins
+    w1, ids1, _ = moe.route(x, dict(p, router_bias=bias), k, "sigmoid", 2.5)
+    assert bool((ids1 == E - 1).any(-1).all())
+    assert not bool((ids0 == E - 1).any(-1).all())
+    scores = jax.nn.sigmoid(x @ w)
+    for ids, wt in ((ids0, w0), (ids1, w1)):
+        s = jnp.take_along_axis(scores, ids, -1)
+        np.testing.assert_allclose(np.asarray(wt),
+                                   np.asarray(s / s.sum(-1, keepdims=True)
+                                              * 2.5), rtol=1e-6)

@@ -155,3 +155,31 @@ def test_qwen3_4b_masked_decode_step_keeps_the_one_hot_write(topo):
     layer = k.shape[1:]
     assert _ops(text, "select", layer) or _ops(text, "select", (1,) + layer)
     assert not _ops(text, "scatter", k.shape)
+
+
+def test_moonlight_expert_layer_compiles_at_cell_widths(one_chip):
+    """The dropless MoE layer of the moonlight-16b-a3b.train-8k cell, its
+    forward and backward at 2 x 8192 tokens: 8 held of 64 routed experts,
+    6 a token, and the shared experts; the held experts' products are
+    grouped matmuls."""
+    from repro.models import moe
+    cfg = get_config("moonlight-16b-a3b")
+    d, held, T = cfg.d_model, 8, 2 * 8192
+    specs = moe.moe_specs(d, cfg.moe_d_ff, cfg.n_experts, n_held=held,
+                          shared_ff=cfg.n_shared_experts * cfg.moe_d_ff,
+                          router_bias=True)
+    p = jax.tree.map(lambda s: _struct(s.shape, jnp.float32, one_chip),
+                     param_structs(specs))
+    x = _struct((2, T // 2, d), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        y, st = moe.apply_moe(x, p, top_k=cfg.top_k, dispatch="sort",
+                              scoring="sigmoid",
+                              routed_scale=cfg.routed_scale)
+        return jnp.sum(y.astype(jnp.float32)), st["moe_assigned"]
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        p, x).compile()
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5 * HBM_BYTES
