@@ -9,9 +9,11 @@ Implementations:
     signature on TPU via kernels/ops.py.
 
 Latent attention (MLA, DeepSeek-V2/V3) has its own projections
-(``mla_specs``/``mla_layer``) and attends through the same ``attend``: its
-q and k are [nope | rope] per head and wider than v.  It is wired for
-training and full forwards only; a latent decode cache does not exist yet.
+(``mla_specs``/``mla_layer``): its q and k are [nope | rope] per head and
+wider than v.  On a TPU with attention not partitioned it attends through
+the fused splash kernel (``kernels/ops.py`` ``causal_attention``),
+elsewhere through the same ``attend``.  It is wired for training and full
+forwards only; a latent decode cache does not exist yet.
 
 Decode attends a single new token against a KV cache; for long contexts the
 cache's sequence dim may be sharded (tiling plan "kv_seq"), in which case the
@@ -27,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.sharding import ParamSpec
+from ..kernels import ops
 from . import layers
 
 NEG_INF = -1e30
@@ -84,7 +87,13 @@ def mla_layer(x, p, cfg, *, impl: str = "chunked"):
     rope).  RoPE pairs dimension i with i + rope/2 of the rope part
     (rotate-half); Hugging Face's DeepseekV3 stores those dimensions
     interleaved and de-interleaves them before the same rotation, which
-    is a fixed permutation of the rope columns of Wq and Wkv_a."""
+    is a fixed permutation of the rope columns of Wq and Wkv_a.
+
+    Where ``ops.causal_attention_applies`` (a TPU, attention not
+    partitioned) the attention runs as the fused Pallas kernel instead of
+    the chunked jnp blocks (``_mla_fused``)."""
+    if impl == "chunked" and ops.causal_attention_applies():
+        return _mla_fused(x, p, cfg)
     dt = x.dtype
     nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
     with jax.named_scope("mla"):
@@ -104,6 +113,31 @@ def mla_layer(x, p, cfg, *, impl: str = "chunked"):
         o = attend(q, k, v, impl=impl, causal=True, q_chunk=cfg.q_chunk,
                    kv_chunk=cfg.kv_chunk)
         return jnp.einsum("bqhk,hkd->bqd", o, p["wo"].astype(dt))
+
+
+def _mla_fused(x, p, cfg):
+    """``mla_layer`` through ``ops.causal_attention``: q, k and v are built
+    in the kernel's [B, H, S, d] order by the projections' einsums, and q
+    takes the softmax scale in float32 before its one cast to x's dtype."""
+    dt = x.dtype
+    nope, rope, r = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla"):
+        pos = jnp.arange(x.shape[1])
+        q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"].astype(dt),
+                       preferred_element_type=jnp.float32)
+        q_pe = layers.apply_rope(q[..., None, nope:], pos, cfg.rope_theta)
+        q = (jnp.concatenate([q[..., :nope], q_pe[..., 0, :]], -1)
+             * (1.0 / math.sqrt(nope + rope))).astype(dt)
+        kv = x @ p["wkv_a"].astype(dt)
+        c = layers.rms_norm(kv[..., :r], p["kv_norm"], MLA_KV_NORM_EPS)
+        k_pe = layers.apply_rope(kv[:, None, :, None, r:], pos,
+                                 cfg.rope_theta)[..., 0, :]   # [B, 1, S, rope]
+        kvb = jnp.einsum("bsr,rhk->bhsk", c, p["wkv_b"].astype(dt))
+        k_nope, v = kvb[..., :nope], kvb[..., nope:]
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + (rope,))], -1)
+        o = ops.causal_attention(q, k, v)
+        return jnp.einsum("bhsk,hkd->bsd", o, p["wo"].astype(dt))
 
 
 def project_qkv(x, p, *, positions=None, rope_theta: float = 10000.0,

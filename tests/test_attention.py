@@ -1,11 +1,15 @@
 """Attention correctness: chunked (flash-shape) vs full oracle, decode path,
-cache updates, GQA/windows/offsets."""
+cache updates, GQA/windows/offsets; latent attention's fused kernel path
+and the rule that chooses it."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models import attention
+from helpers import run_devices
+from repro.models import attention, layers
 
 
 @pytest.mark.parametrize("B,S,H,KV,hd,window,causal", [
@@ -119,3 +123,181 @@ def test_rope_rotation_properties():
         kk = layers.apply_rope(k, jnp.array([[n]]))
         return float(jnp.sum(qq * kk))
     assert abs(dot_at(3, 1) - dot_at(7, 5)) < 1e-4
+
+
+# ---------------------------------------------------------------------------
+# Latent attention's fused kernel path (kernels/ops.py causal_attention)
+# ---------------------------------------------------------------------------
+MLA_SCALE = 1 / math.sqrt(192)     # MLA's q.k width; not a power of two
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("S,tile,dtype", [
+    (512, 256, jnp.float32),
+    (1024, None, jnp.float32),
+    (512, 256, jnp.bfloat16),
+    (1024, None, jnp.bfloat16),
+])
+def test_causal_attention_kernel_matches_full(S, tile, dtype, monkeypatch):
+    """The Pallas kernel in the interpreter at MLA's widths (q.k 128 + 64,
+    v 128), causal, the softmax scale folded into q: its output and the
+    gradients of q, k and v against ``attend_full``.  In bfloat16 both
+    paths round; there the kernel's error against the float32 oracle is
+    no larger than that of the chunked path it replaces."""
+    from repro.kernels import ops
+    B, H = 2, 2
+    ks = jax.random.split(jax.random.PRNGKey(S), 4)
+    q = jax.random.normal(ks[0], (B, S, H, 192)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, S, H, 192)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, S, H, 128)).astype(dtype)
+    ct = jax.random.normal(ks[3], (B, S, H, 128)).astype(dtype)
+    if tile is not None:
+        monkeypatch.setattr(ops, "CAUSAL_BLOCKS", {
+            n: b if isinstance(b, bool) else tile
+            for n, b in ops.CAUSAL_BLOCKS.items()})
+    t = lambda a: a.transpose(0, 2, 1, 3)
+
+    def kernel(q, k, v):
+        qs = (q.astype(jnp.float32) * MLA_SCALE).astype(dtype)
+        return t(ops.causal_attention(t(qs), t(k), t(v), interpret=True))
+
+    def full(q, k, v):
+        return attention.attend_full(q, k, v, scale=MLA_SCALE)
+
+    def chunked(q, k, v):
+        return attention.attend_chunked(q, k, v, q_chunk=256, kv_chunk=256,
+                                        scale=MLA_SCALE)
+
+    def run(f, *args):
+        o, vjp = jax.vjp(f, *args)
+        return (o,) + vjp(ct.astype(o.dtype))
+
+    got = run(kernel, q, k, v)
+    if dtype == jnp.float32:
+        for a, b in zip(got, run(full, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)
+        return
+    f32 = lambda a: a.astype(jnp.float32)
+    oracle = run(full, f32(q), f32(k), f32(v))
+    for a, c, o in zip(got, run(chunked, q, k, v), oracle):
+        assert a.dtype == jnp.bfloat16
+        assert _rel(a, o) <= _rel(c, o) < 1e-2
+
+
+def _mla_cfg():
+    import dataclasses
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("moonlight-16b-a3b"), d_model=64,
+                               n_heads=2, n_kv_heads=2, kv_lora_rank=32)
+
+
+def _mla_weights(cfg, key):
+    from repro.core.sharding import param_structs
+    specs = attention.mla_specs(cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                                cfg.qk_nope_dim, cfg.qk_rope_dim,
+                                cfg.v_head_dim)
+    leaves, treedef = jax.tree.flatten(param_structs(specs))
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        0.1 * jax.random.normal(k, s.shape) for k, s in zip(keys, leaves)])
+
+
+def _parent_mla_layer(x, p, cfg):
+    """``mla_layer`` as it was before the kernel path existed."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    pos = jnp.arange(x.shape[1])[None, :]
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    kv = x @ p["wkv_a"].astype(dt)
+    c = layers.rms_norm(kv[..., :r], p["kv_norm"], attention.MLA_KV_NORM_EPS)
+    k_pe = layers.apply_rope(kv[..., None, r:], pos, cfg.rope_theta)
+    kvb = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"].astype(dt))
+    k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    q = jnp.concatenate(
+        [q[..., :nope], layers.apply_rope(q[..., nope:], pos, cfg.rope_theta)],
+        -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1]
+                                  + (k_pe.shape[-1],))], -1)
+    o = attention.attend(q, k, v, causal=True, q_chunk=cfg.q_chunk,
+                         kv_chunk=cfg.kv_chunk)
+    return jnp.einsum("bqhk,hkd->bqd", o, p["wo"].astype(dt))
+
+
+def test_mla_layer_off_the_tpu_is_the_chunked_path():
+    """On the CPU ``mla_layer`` takes the chunked path: no kernel in its
+    program, and its output bit for bit what it was before the kernel path
+    (the reference checks of tests/test_moonlight.py cover that code)."""
+    from repro.kernels import ops
+    assert not ops.causal_attention_applies()
+    cfg = _mla_cfg()
+    p = _mla_weights(cfg, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 4096, cfg.d_model))
+    layer = jax.jit(lambda x, p: attention.mla_layer(x, p, cfg))
+    assert "tpu_custom_call" not in layer.lower(x, p).as_text()
+    np.testing.assert_array_equal(
+        np.asarray(layer(x, p)),
+        np.asarray(jax.jit(lambda x, p: _parent_mla_layer(x, p, cfg))(x, p)))
+
+
+def test_mla_layer_partitioned_attention_is_the_chunked_path():
+    """Where the backend is a TPU but an activation hook's mesh has more
+    than one device, the attention is partitioned and ``mla_layer`` lowers
+    to the chunked path; with a one-device mesh it takes the kernel."""
+    out = run_devices("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import get_config
+        from repro.core.sharding import act_hook, default_rules
+        from repro.kernels import ops
+        from repro.models import attention
+        ops._on_tpu = lambda: True
+        cfg = get_config("moonlight-16b-a3b")
+        d = np.array(jax.devices())
+        x = jnp.zeros((2, 256, 64))
+        p = {"wq": jnp.zeros((64, 16, 192)), "wkv_a": jnp.zeros((64, 576)),
+             "kv_norm": jnp.ones((512,)),
+             "wkv_b": jnp.zeros((512, 16, 256)),
+             "wo": jnp.zeros((16, 128, 64))}
+        with act_hook(Mesh(d[:1].reshape(1, 1), ("data", "model")),
+                      default_rules()):
+            one = ops.causal_attention_applies()
+        with act_hook(Mesh(d.reshape(1, 2), ("data", "model")),
+                      default_rules()):
+            two = ops.causal_attention_applies()
+            text = jax.jit(lambda x, p: attention.mla_layer(x, p, cfg)
+                           ).lower(x, p).as_text()
+        print(one, two, "tpu_custom_call" in text, "dot_general" in text)
+    """, n_devices=2)
+    assert out.split() == ["True", "False", "False", "True"]
+
+
+def test_mla_layer_kernel_path_matches_chunked_path(monkeypatch):
+    """The kernel path of ``mla_layer`` (q, k, v in [B, H, S, d], the
+    scale folded into q, the kernel in the interpreter) against its
+    chunked path, in float32: the output and every gradient."""
+    import functools
+    from repro.kernels import ops
+    cfg = _mla_cfg()
+    p = _mla_weights(cfg, jax.random.PRNGKey(2))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 256, cfg.d_model))
+    ct = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def run():
+        out, vjp = jax.vjp(lambda x, p: attention.mla_layer(x, p, cfg), x, p)
+        return [out] + jax.tree.leaves(vjp(ct))
+
+    want = run()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "causal_attention", functools.partial(
+        ops.causal_attention, interpret=True))
+    assert ops.causal_attention_applies()
+    got = run()
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)

@@ -183,3 +183,44 @@ def test_moonlight_expert_layer_compiles_at_cell_widths(one_chip):
     assert "ragged-dot" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.5 * HBM_BYTES
+
+
+def _mla_layer_grad(one_chip, monkeypatch, kernel):
+    """One MLA layer of the moonlight-16b-a3b.train-8k cell, its forward
+    and backward at 2 x 8192 tokens (16 heads, q.k 128 + 64, v 128),
+    compiled on the kernel path or on the chunked path."""
+    from repro.kernels import ops
+    from repro.models import attention
+    monkeypatch.setattr(ops, "_on_tpu", lambda: kernel)
+    cfg = get_config("moonlight-16b-a3b")
+    specs = attention.mla_specs(cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                                cfg.qk_nope_dim, cfg.qk_rope_dim,
+                                cfg.v_head_dim)
+    p = jax.tree.map(lambda s: _struct(s.shape, jnp.float32, one_chip),
+                     param_structs(specs))
+    x = _struct((2, 8192, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        return jnp.sum(attention.mla_layer(x, p, cfg).astype(jnp.float32))
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile()
+
+
+def test_moonlight_mla_layer_runs_the_fused_kernel(one_chip, monkeypatch):
+    """On the kernel path the compiled layer holds the Pallas kernel's
+    forward and backward (dq and dkv fused) custom calls, each under the ``mla`` scope that
+    bench/scopes.py reads, and plans fewer temporary bytes than the
+    chunked path (no float32 logits block reaches HBM)."""
+    compiled = _mla_layer_grad(one_chip, monkeypatch, kernel=True)
+    text = compiled.as_text()
+    # a Pallas call's text spans lines (its kernel metadata): read each
+    # call's op_name from its own instruction onwards
+    calls = dict(re.findall(r"%(splash_mha_\w+?)(?:\.\d+)? = .*?"
+                            r"op_name=\"([^\"]*)\"", text, re.S))
+    assert {"splash_mha_fwd_residuals",
+            "splash_mha_dkv_no_residuals"} <= set(calls)
+    for op_name in calls.values():
+        assert re.search(r"(?:^|[/(])mla(?=[/)]|$)", op_name), op_name
+    chunked = _mla_layer_grad(one_chip, monkeypatch, kernel=False)
+    assert "tpu_custom_call" not in chunked.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < chunked.memory_analysis().temp_size_in_bytes
