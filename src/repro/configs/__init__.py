@@ -16,12 +16,3 @@ ARCH_IDS = sorted(REGISTRY)
 def get_config(name: str, *, tiny: bool = False) -> ArchConfig:
     cfg = REGISTRY[name]
     return cfg.tiny() if tiny else cfg
-
-
-# the paper's own benchmark input shapes (Fig. 1)
-SHAPES = {
-    "train_4k": dict(seq_len=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524288, global_batch=1, kind="decode"),
-}

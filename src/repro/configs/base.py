@@ -90,7 +90,6 @@ class ArchConfig:
     # metadata
     source: str = ""
     aux_weight: float = 0.01
-    subquadratic: bool = False       # eligible for long_500k
 
     def __post_init__(self):
         if self.head_dim == 0:

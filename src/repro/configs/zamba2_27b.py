@@ -1,7 +1,7 @@
 """zamba2-2.7b [hybrid]: Mamba2 backbone + one shared attention+MLP block
 applied every 6 mamba layers [arXiv:2411.15242].  Simplifications noted in
 DESIGN.md: shared block on the residual stream (no concat-with-embedding or
-per-application LoRA).  Sub-quadratic -> runs long_500k."""
+per-application LoRA)."""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -11,6 +11,5 @@ CONFIG = ArchConfig(
     expand=2, ssm_head_dim=64, ssm_state=64, ssm_groups=1, ssm_d_conv=4,
     shared_every=6,
     norm="rms", mlp_kind="swiglu",
-    subquadratic=True,
     source="arXiv:2411.15242",
 )

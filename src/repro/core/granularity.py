@@ -12,16 +12,17 @@ from static shape/mesh arithmetic instead of per task at runtime:
 ``GrainPolicy.derive`` does the napkin math: it balances per-collective fixed
 latency against the bandwidth cost of delaying overlap (bigger buckets start
 later), and activation memory against recompute FLOPs.  Every decision is
-returned with the numbers that produced it so logs/EXPERIMENTS.md can show
-*why* a grain was picked - the paper's "runtime-adaptive" requirement made
-auditable.
+returned with the numbers that produced it in ``rationale``, so a caller
+can show *why* a grain was picked - the paper's "runtime-adaptive"
+requirement made auditable.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Any
 
-# TPU v5e model constants (per chip) - same numbers as the roofline.
+# TPU v5e model constants (per chip); the benchmark keeps its own peaks in
+# bench/peaks.json.
 PEAK_FLOPS = 197e12          # bf16
 HBM_BW = 819e9               # bytes/s
 ICI_BW = 50e9                # bytes/s/link

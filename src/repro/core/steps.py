@@ -549,13 +549,10 @@ def make_decode_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
 
 def make_step(cfg=None, mesh=None, strategy: Optional[Strategy] = None,
               shape: Optional[dict] = None, *, plan=None):
-    if shape is None and plan is not None:
-        if plan.shape is None:
-            raise ValueError(
-                "make_step(plan=...) dispatches on shape['kind']: give the "
-                "Plan a named shape or pass shape= explicitly (or call "
-                "make_train_step/make_prefill_step/make_decode_step)")
-        shape = plan.shape_of("train")   # named Plan shapes carry their kind
+    if shape is None:
+        raise ValueError(
+            "make_step dispatches on shape['kind']: pass shape= (or call "
+            "make_train_step/make_prefill_step/make_decode_step)")
     kind = shape["kind"]
     if kind == "train":
         return make_train_step(cfg, mesh, strategy, shape, plan=plan)

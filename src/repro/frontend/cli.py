@@ -1,6 +1,6 @@
 """Shared launcher flags: the `--arch/--tiny/--data/--model/--seq/--batch`
-block that was copied across launch/train.py, launch/serve.py and
-launch/dryrun.py lives here once, and maps 1:1 onto ``Plan`` fields."""
+block of launch/train.py and launch/serve.py lives here once, and maps 1:1
+onto ``Plan`` fields."""
 from __future__ import annotations
 
 import argparse

@@ -5,16 +5,15 @@ shapes - a frozen value that touches no device state.  ``plan.compile()``
 builds a ``Session``: the mesh is made, step functions are jitted lazily,
 and ONE futurized runtime (`core/futures.py`) owns every host-side task of
 the session - prefetch, metric forcing, checkpoint I/O, serve wave prep and
-the decode chain.  ``session.train`` / ``session.serve`` / ``session.dryrun``
-subsume the old launcher bodies; ``launch/{train,serve,dryrun}.py`` are now
-thin argparse shims over this API (DESIGN.md §8).
+the decode chain.  ``session.train`` / ``session.serve`` subsume the old
+launcher bodies; ``launch/{train,serve}.py`` are now thin argparse shims
+over this API (DESIGN.md §8).
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import time
-import traceback
 from typing import Any, Optional
 
 import jax
@@ -22,26 +21,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint.checkpoint import CheckpointManager
-from ..configs import SHAPES, get_config
-from ..core import hlo_costs
+from ..configs import get_config
 from ..core import steps as steps_lib
 from ..core.futures import FuturizedGraph, Lane, Pipeline
 from ..core.resilience import ResilientRunner
-from ..core.sharding import init_params, param_structs
+from ..core.sharding import init_params
 from ..data.pipeline import Prefetcher, stream_for
-from ..launch.mesh import make_local_mesh, make_production_mesh, mesh_devices
+from ..launch.mesh import make_local_mesh
 from .futurize import Trace
 
-__all__ = ["Plan", "Session", "cell_is_applicable", "lower_cell",
-           "roofline_terms"]
-
-# TPU v5e roofline model constants (per chip); used by session.dryrun and
-# the launch/dryrun.py sweep
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW_PER_LINK = 50e9
-ICI_LINKS = 3
-HBM_BYTES = 16e9
+__all__ = ["Plan", "Session"]
 
 
 def _stack_wave(wave):
@@ -60,15 +49,11 @@ class Plan:
     Fields:
         arch: architecture id from ``configs.ARCH_IDS``.
         tiny: use the reduced smoke-scale config.
-        data, model, pod: local mesh axis sizes (``mesh="local"``).
-        mesh: "local" (axis sizes over host devices) or "single" /
-            "multipod" (the production 256/512-chip meshes).
+        data, model, pod: mesh axis sizes over the local devices.
         strategy: a ``core.steps.Strategy`` or a bare name
             ("phylanx" | "horovod" | "zero1" | "onebit").
-        batch, seq: global batch and sequence length when no named
-            ``shape`` is given.
+        batch, seq: global batch and sequence length.
         seed: PRNG seed for params and synthetic streams.
-        shape: optionally a named cell of ``configs.SHAPES`` (dry-run).
         remat: enable rematerialization on tiny configs.
         localities: total process count for the multi-locality runtime
             (DESIGN.md §9).  1 runs everything in-process; N > 1 spawns
@@ -133,12 +118,10 @@ class Plan:
     data: int = 1
     model: int = 1
     pod: int = 1
-    mesh: str = "local"                  # local | single | multipod
     strategy: Any = "phylanx"
     batch: int = 8
     seq: int = 64
     seed: int = 0
-    shape: Optional[str] = None          # named SHAPES cell (dryrun)
     remat: bool = False
     localities: int = 1                  # processes incl. the driver
     spmd: bool = False                   # jax.distributed SPMD mode (§10)
@@ -160,10 +143,7 @@ class Plan:
         return dataclasses.replace(cfg, **over) if over else cfg
 
     def build_mesh(self):
-        if self.mesh == "local":
-            return make_local_mesh(data=self.data, model=self.model,
-                                   pod=self.pod)
-        return make_production_mesh(multi_pod=(self.mesh == "multipod"))
+        return make_local_mesh(data=self.data, model=self.model, pod=self.pod)
 
     def build_strategy(self) -> steps_lib.Strategy:
         if isinstance(self.strategy, steps_lib.Strategy):
@@ -171,8 +151,6 @@ class Plan:
         return steps_lib.Strategy(name=self.strategy)
 
     def shape_of(self, kind: str) -> dict:
-        if self.shape is not None:
-            return dict(SHAPES[self.shape])
         return {"seq_len": self.seq, "global_batch": self.batch,
                 "kind": kind}
 
@@ -1104,133 +1082,3 @@ class Session:
                   f"epochs (page hits {st.serve.get('page_hits', 0)}/"
                   f"{st.serve.get('refills', 0)} refills)")
         return out
-
-    # -- dryrun -------------------------------------------------------------
-    def dryrun(self, shape: Optional[str] = None) -> dict:
-        """Lower + compile this plan's cell and return its analysis record
-        (memory, loop-aware HLO costs, collectives, roofline terms) - the
-        per-cell body of ``launch/dryrun.py``.
-
-        Args:
-            shape: named ``configs.SHAPES`` cell; defaults to
-                ``plan.shape``.
-        Returns:
-            dict with ``status`` ("ok" | "skipped" | "error") plus, when
-            ok, device counts, lower/compile times, per-device flops and
-            bytes, memory analysis, collectives, and roofline terms.
-        Raises:
-            ValueError: neither ``shape`` nor ``plan.shape`` is set.
-        """
-        shape_name = shape or self.plan.shape
-        if shape_name is None:
-            raise ValueError("dryrun needs a named shape (Plan.shape or "
-                             "the shape= argument)")
-        cfg, mesh = self.cfg, self.mesh
-        ok, why = cell_is_applicable(cfg, shape_name)
-        if not ok:
-            return {"status": "skipped", "reason": why}
-        n_dev = mesh_devices(mesh)
-        try:
-            step, lowered, compiled, t_lower, t_compile = lower_cell(
-                cfg, mesh, shape_name, self.strategy)
-            ca = compiled.cost_analysis() or {}
-            try:
-                ma = compiled.memory_analysis()
-                mem = {
-                    "argument_bytes": ma.argument_size_in_bytes,
-                    "output_bytes": ma.output_size_in_bytes,
-                    "temp_bytes": ma.temp_size_in_bytes,
-                    "alias_bytes": ma.alias_size_in_bytes,
-                    "code_bytes": ma.generated_code_size_in_bytes,
-                }
-                mem["peak_bytes_est"] = (mem["argument_bytes"]
-                                         + mem["output_bytes"]
-                                         - mem["alias_bytes"]
-                                         + mem["temp_bytes"])
-            except Exception as e:  # pragma: no cover
-                mem = {"error": str(e)}
-            # loop-aware analysis (cost_analysis counts while bodies once;
-            # see core/hlo_costs.py) - the roofline source of truth
-            costs = hlo_costs.analyze(compiled.as_text(), n_dev)
-            terms = roofline_terms(cfg, shape_name, costs.flops, costs.bytes,
-                                   costs.total_wire_bytes, n_dev)
-            return {
-                "status": "ok", "n_devices": n_dev,
-                "t_lower_s": t_lower, "t_compile_s": t_compile,
-                "flops_per_device": costs.flops,
-                "bytes_per_device": costs.bytes,
-                "memory": mem, "collectives": costs.to_json(),
-                "roofline": terms,
-                "xla_cost_analysis": {
-                    "flops": float(ca.get("flops", 0.0)),
-                    "bytes_accessed": float(ca.get("bytes accessed", 0.0))},
-                "fits_hbm": bool(mem.get("peak_bytes_est", 0) < HBM_BYTES),
-            }
-        except Exception as e:
-            return {"status": "error", "error": f"{type(e).__name__}: {e}",
-                    "traceback": traceback.format_exc()[-4000:]}
-
-
-# ---------------------------------------------------------------------------
-# Cell analysis helpers (shared with launch/dryrun.py and benchmarks)
-# ---------------------------------------------------------------------------
-def cell_is_applicable(cfg, shape_name: str) -> tuple[bool, str]:
-    if shape_name == "long_500k" and not cfg.subquadratic:
-        return False, ("long_500k needs sub-quadratic attention "
-                       "(skip noted in DESIGN.md)")
-    if cfg.mla and SHAPES[shape_name]["kind"] != "train":
-        return False, "latent attention (MLA) has no decode cache"
-    return True, ""
-
-
-def lower_cell(cfg, mesh, shape_name: str, strategy: steps_lib.Strategy):
-    shape = dict(SHAPES[shape_name])
-    kind = shape["kind"]
-    step = steps_lib.make_step(cfg, mesh, strategy, shape)
-
-    if kind == "train":
-        args = (step.param_structs(), step.opt_structs(),
-                steps_lib.input_specs(cfg, shape))
-    elif kind == "prefill":
-        scfg = steps_lib._serve_cfg(cfg)
-        args = (param_structs(step.specs),
-                steps_lib.input_specs(scfg, shape))
-    else:  # decode
-        scfg = steps_lib._serve_cfg(cfg)
-        args = (param_structs(step.specs), param_structs(step.cache_specs),
-                steps_lib.input_specs(scfg, shape),
-                jax.ShapeDtypeStruct((), jnp.int32))
-
-    t0 = time.time()
-    lowered = step.fn.lower(*args)
-    t_lower = time.time() - t0
-    t0 = time.time()
-    compiled = lowered.compile()
-    t_compile = time.time() - t0
-    return step, lowered, compiled, t_lower, t_compile
-
-
-def roofline_terms(cfg, shape_name: str, flops_dev: float, bytes_dev: float,
-                   wire_bytes_dev: float, n_dev: int) -> dict:
-    shape = SHAPES[shape_name]
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = wire_bytes_dev / (ICI_BW_PER_LINK * ICI_LINKS)
-    dominant = max(("compute", t_compute), ("memory", t_memory),
-                   ("collective", t_coll), key=lambda kv: kv[1])[0]
-    # useful model flops: 6 N D (train) / 2 N D (fwd) per token
-    tot, act = cfg.n_params()
-    tokens = shape["global_batch"] * (shape["seq_len"]
-                                      if shape["kind"] != "decode" else 1)
-    mult = 6 if shape["kind"] == "train" else 2
-    model_flops = mult * act * tokens / n_dev
-    return {
-        "t_compute_s": t_compute, "t_memory_s": t_memory,
-        "t_collective_s": t_coll, "dominant": dominant,
-        "model_flops_dev": model_flops,
-        "useful_flops_ratio": model_flops / flops_dev if flops_dev else 0.0,
-        "bound_step_s": max(t_compute, t_memory, t_coll),
-        "roofline_fraction": (t_compute / max(t_compute, t_memory, t_coll)
-                              if max(t_compute, t_memory, t_coll) > 0
-                              else 0.0),
-    }

@@ -44,15 +44,8 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_local_mesh(data: int = 1, model: int = 1, pod: int = 1):
-    """Small mesh over host devices for tests/examples/benchmarks.
+    """Mesh over the local devices for the frontend, tests and examples.
 
     Under ``jax.distributed`` (SPMD mode) the mesh is built from THIS
     process's ``jax.local_devices()`` only: the CPU backend cannot run
@@ -68,13 +61,6 @@ def make_local_mesh(data: int = 1, model: int = 1, pod: int = 1):
     if jax.process_count() > 1:
         devices = jax.local_devices()
     return make_mesh(tuple(shape), tuple(axes), devices=devices)
-
-
-def mesh_devices(mesh) -> int:
-    n = 1
-    for v in mesh.shape.values():
-        n *= v
-    return n
 
 
 def free_port() -> int:

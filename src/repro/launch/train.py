@@ -52,8 +52,8 @@ from repro.launch.mesh import use_compile_cache
 class _StallHook:
     """Driver-side 250 ms stall at one step: the joined locality drains
     its queue, goes hungry, and the next steerable prefetch build is
-    diverted to it - the deterministic steal window the churn tests and
-    ``benchmarks/elastic_scaleout.py`` use (DESIGN.md §13)."""
+    diverted to it - the deterministic steal window the churn tests use
+    (DESIGN.md §13)."""
 
     def __init__(self, at: int):
         self.at = at

@@ -1,10 +1,10 @@
 """Architecture assembly: decoder LMs (dense/moe/xlstm/zamba) and enc-dec.
 
 Full-size configs scan over stacked per-layer parameters (small HLO, fast
-512-way SPMD compiles) with per-block rematerialization; tiny configs run
+SPMD compiles) with per-block rematerialization; tiny configs run
 the same code paths on CPU for smoke tests.
 
-Contract (used by core.steps, launch.dryrun, examples):
+Contract (used by core.steps, frontend, examples):
   m = build_model(cfg)
   m.specs()                                  ParamSpec tree
   m.apply(params, batch)                  -> (logits, aux)     train fwd

@@ -2,8 +2,8 @@
 
 All three follow the same contract as attention: a *parallel/chunked* form
 for training & prefill (sub-quadratic, O(L) memory in chunks) and a *step*
-form for decode carrying an explicit recurrent state - which is what makes
-the ``long_500k`` shape feasible for the ssm/hybrid architectures.
+form for decode carrying an explicit recurrent state, so a decode step's
+memory does not grow with the context.
 
 Chunked algorithms are validated against direct sequential recurrences in
 tests/test_ssm.py; the Pallas mamba2 chunk kernel mirrors the same block

@@ -36,6 +36,13 @@ def nap_id(i, delay=0.05):
     return i
 
 
+def gated_nap_id(i, gate, delay=0.1):
+    """``nap_id`` held until the file ``gate`` exists."""
+    while not os.path.exists(gate):
+        time.sleep(0.01)
+    return nap_id(i, delay)
+
+
 def _assert_procs_reaped(pids, timeout=30.0):
     """Every worker pid must be gone (reaped, not just zombied) soon
     after close - the no-orphans half of the churn acceptance."""
@@ -166,15 +173,23 @@ def test_simultaneous_join_and_kill_original_peer():
 
 # -- steal modes on a bare DistributedGraph -----------------------------------
 
-def test_backlog_steal_after_join_spares_pinned_tasks():
+def test_backlog_steal_after_join_spares_pinned_tasks(tmp_path):
     """Victim-lease stealing: a worker with a deep queue of steerable
     tasks loses some of them to a fresh joiner - but explicitly pinned
-    (``locality=``) tasks are never stealable."""
+    (``locality=``) tasks are never stealable.  The backlog waits on a
+    gate opened only after the join, so the joiner always finds it."""
+    gate = str(tmp_path / "gate")
     g = DistributedGraph(localities=2, elastic=True)
     try:
-        futs = [g.defer(nap_id, i, delay=0.1, name=f"p{i}")
+        futs = [g.defer(gated_nap_id, i, gate, name=f"p{i}")
                 for i in range(24)]
         rank = g.add_locality(timeout=120)
+        # the joiner steals within a second or two; the wait stays under
+        # the 30 s after which the fabric drops a silent dialed link
+        deadline = time.monotonic() + 20
+        while g.stolen_tasks == 0 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        open(gate, "w").close()
         assert [f.result(timeout=120) for f in futs] == list(range(24))
         s = g.stats()
         assert s["stolen_tasks"] > 0

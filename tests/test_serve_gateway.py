@@ -347,46 +347,57 @@ def test_kill_last_replica_revives_on_driver():
 
 # -- gateway bugfix sweep: CV wake, submit/close race ------------------------
 
+class _ParkingQueue(RequestQueue):
+    """Records every park of the idle gateway: the timeout it waited
+    with, and what it found when it woke."""
+
+    def __init__(self):
+        super().__init__()
+        self.parked = threading.Event()
+        self.wakes: list = []
+
+    def wait_nonempty(self, timeout=None):
+        self.parked.set()
+        woke = super().wait_nonempty(timeout)
+        with self._lock:
+            self.wakes.append((timeout, len(self._items), self.closed))
+        return woke
+
+
 def test_idle_gateway_wakes_on_submit_without_polling_latency():
     """The idle gateway parks on the queue condition variable (no more
-    20 Hz poll): a submission to an idle gateway reaches prefill fast, so
-    the queue_wait p50 lands strictly below the 10 ms bucket where the
-    old 0-50 ms poll jitter used to put it."""
+    20 Hz poll): every submission and the close are made to a parked
+    gateway, and each wakes it exactly once - no park waits with a poll
+    timeout, and no park returns before a submission or close."""
     with _plan().compile() as session:
         rng = np.random.default_rng(7)
         prompts = [rng.integers(0, session.cfg.vocab, 16).astype(np.int32)
                    for _ in range(6)]
-        q = RequestQueue()
+        q = _ParkingQueue()
 
         def feeder():
-            # warm-up: first request compiles prefill/decode while the
-            # clock is NOT running against later arrivals
-            q.submit(prompts[0]).result(timeout=120)
-            for p in prompts[1:]:
-                time.sleep(0.03)        # gateway is idle-parked each time
-                q.submit(p)
-            q.close()
+            try:        # submit only to a parked gateway, then close
+                for p in prompts:
+                    if not q.parked.wait(timeout=300):
+                        return
+                    q.parked.clear()
+                    q.submit(p)
+                q.parked.wait(timeout=300)
+            finally:
+                q.close()
 
         t = threading.Thread(target=feeder)
         t.start()
         out = session.serve_stream(queue=q, **_kwargs(gen_len=2))
-        t.join()
+        t.join(timeout=60)
+        assert not t.is_alive()
     assert out["completed"] == len(prompts)
     hist = out["runtime_stats"]["request_latency_hist"]
-    counts = hist["counts"]["queue_wait"]
-    total = sum(counts)
-    assert total == len(prompts)
-    # p50 bucket index: first bucket where the cumulative count crosses
-    # half the samples.  Buckets 0..2 are <100us, <1ms, <10ms.
-    acc, p50_bucket = 0, len(counts) - 1
-    for i, c in enumerate(counts):
-        acc += c
-        if acc * 2 >= total:
-            p50_bucket = i
-            break
-    assert p50_bucket <= 2, (
-        f"queue_wait p50 in bucket {hist['labels'][p50_bucket]} - the CV "
-        f"wake regressed to polling latency ({counts})")
+    assert sum(hist["counts"]["queue_wait"]) == len(prompts)
+    # one wake per submission (finding exactly that request), then the
+    # close; a polling park would show a timeout or an empty wake
+    assert q.wakes == ([(None, 1, False)] * len(prompts)
+                       + [(None, 0, True)]), q.wakes
 
 
 def test_submit_racing_close_is_atomic_at_the_queue():

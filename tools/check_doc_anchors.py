@@ -5,7 +5,8 @@ Every ``DESIGN.md#<anchor>`` markdown link and every textual
 plus every ``§N`` mention inside DESIGN.md itself, and the ``DESIGN.md
 §N`` pointers embedded in source docstrings of the phylint tooling and
 the CI workflow - must resolve to a real DESIGN.md heading.  Run by the
-``docs`` CI job next to the generated-API staleness gate, so renaming or
+``docs`` CI job next to the generated-API staleness gate, and by
+tests/test_docs.py, so renaming or
 deleting a DESIGN.md section without fixing its referrers fails the
 build.
 

@@ -3,8 +3,8 @@
 The docstring audit (DESIGN.md §9 satellite) made every public symbol of
 the futurized runtime, the frontend, and the multi-locality runtime
 carry args/returns/raises; this script turns those docstrings into one
-browsable reference so the docs can never drift silently - CI runs
-``--check`` and fails when docs/API.md is stale.
+browsable reference so the docs can never drift silently - CI and
+tests/test_docs.py run ``--check``, which fails when docs/API.md is stale.
 
     PYTHONPATH=src python tools/gen_api_docs.py            # regenerate
     PYTHONPATH=src python tools/gen_api_docs.py --check    # CI gate
@@ -47,9 +47,8 @@ API = [
     ("repro.frontend", [
         "Plan", "Plan.compile",
         "Session", "Session.train", "Session.serve",
-        "Session.serve_stream", "Session.dryrun",
-        "Session.close", "Session.stats", "Session.kill_locality",
-        "Session.add_locality", "Session.lint",
+        "Session.serve_stream", "Session.close", "Session.stats",
+        "Session.kill_locality", "Session.add_locality", "Session.lint",
         "futurize", "tracing", "Trace", "serve_flags",
     ]),
     ("repro.frontend.gateway", [
@@ -140,8 +139,8 @@ HEADER = """\
 *Generated by `tools/gen_api_docs.py` from source docstrings - do not
 edit by hand; regenerate with*
 `PYTHONPATH=src python tools/gen_api_docs.py`.
-*CI (`.github/workflows/ci.yml`, multiproc job) fails when this file is
-stale.*
+*CI (`.github/workflows/ci.yml`, multiproc job) and `tests/test_docs.py`
+fail when this file is stale.*
 """
 
 
